@@ -27,7 +27,7 @@ test:
 	  $(MAKE) --no-print-directory dist-smoke; \
 	fi
 
-# downsized perf gate (≤~30s): device-aggregate worker only, fails when the
+# downsized CPU-only perf gate (≤~30s, 8-device CPU mesh): fails when the
 # oracle-normalized groupby_aggregate vs_baseline drops >20% below the
 # recorded value (BENCH_SMOKE_BASELINE.json for this env, else BENCH_r05).
 # --compare is a BLOCKING gate (exit 8 on any metric regression vs the

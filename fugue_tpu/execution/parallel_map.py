@@ -32,7 +32,8 @@ worker processes, and recovery follows the graceful-degradation order
 Every recovery step increments the engine's ``resilience_stats``.
 
 Not engaged when:
-- the platform has no ``fork`` (non-Linux/macOS spawn semantics),
+- the platform has no ``fork`` (non-Linux/macOS spawn semantics), or the
+  process has loaded the TPU runtime, whose threads do not survive a fork,
 - the transformer carries a worker→driver RPC callback (the in-process
   ``NativeRPCServer`` can't cross a process boundary; such transformers run
   serially, matching the reference's local engine),
@@ -82,10 +83,31 @@ _POLL_INTERVAL = 0.01
 
 
 def fork_available() -> bool:
+    """The one rule for forking in this repo (the UDF pool here, the bench
+    chaos smokes' replicas): only where ``fork`` exists and this process
+    has not loaded the TPU runtime."""
     try:
-        return "fork" in mp.get_all_start_methods()
+        return "fork" in mp.get_all_start_methods() and not _holds_libtpu()
     except Exception:
         return False
+
+
+_LIBTPU_LOADED = False
+
+
+def _holds_libtpu() -> bool:
+    """True once this process has loaded the TPU runtime (a chip backend,
+    or a described topology). Its threads and signal handlers do not
+    survive ``fork``: forked pool workers crash, so the pool runs serial.
+    The runtime is never unloaded, so a True answer is kept."""
+    global _LIBTPU_LOADED
+    if not _LIBTPU_LOADED:
+        try:
+            with open("/proc/self/maps") as f:
+                _LIBTPU_LOADED = any("libtpu" in line for line in f)
+        except OSError:
+            pass
+    return _LIBTPU_LOADED
 
 
 def map_func_parallel_safe(map_func: Callable) -> bool:

@@ -55,7 +55,7 @@ from ..parallel.mesh import (
 from ..schema import Schema
 from .dataframe import JaxDataFrame, _DEVICE_DTYPES
 from ..obs import traced_verb
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _safe_prefix(base: str, *name_sets: Any) -> str:
@@ -724,6 +724,12 @@ class JaxExecutionEngine(ExecutionEngine):
 
         Shim over ``engine.metrics`` — prefer ``engine.stats()["jit_cache"]``."""
         return self._jit_cache.stats()
+
+    @property
+    def last_join_strategy(self) -> Optional[str]:
+        """The rung the most recent device join took (``broadcast``,
+        ``copartition``, ``device_exchange``...), None before any."""
+        return self._last_join_strategy
 
     @property
     def is_distributed(self) -> bool:

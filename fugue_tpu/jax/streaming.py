@@ -58,7 +58,7 @@ from ..dataframe import (
 )
 from ..exceptions import FugueInvalidOperation
 from ..schema import Schema
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 DEFAULT_CHUNK_ROWS = 1 << 20
 

@@ -28,7 +28,7 @@ import numpy as np
 
 from ..column.expressions import _LitColumnExpr, _NamedColumnExpr, _WindowExpr
 from ..schema import Schema
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 _AGGS = {"SUM", "AVG", "MIN", "MAX", "COUNT", "FIRST", "LAST"}
 _RANKS = {"ROW_NUMBER", "RANK", "DENSE_RANK"}

@@ -28,7 +28,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..parallel.mesh import ROW_AXIS, num_row_shards
 from . import collectives
 from .shuffle import _hash_cols
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 _JOIN_CACHE: Dict[Any, Any] = {}
 

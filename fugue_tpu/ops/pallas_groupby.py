@@ -12,10 +12,14 @@ contract:
 - :func:`bin_sum_count_xla` — chunked ``lax.scan`` over rows, one-hot
   compare + matmul per chunk; pure jnp, runs on every backend, and XLA
   fuses the compare into the matmul operand feed.
-- :func:`bin_sum_count_pallas` — a Pallas TPU kernel: grid over row
-  chunks, one-hot partial products accumulated into a VMEM-resident
-  ``(buckets,)`` table across sequential grid steps (no HBM one-hot is
-  ever materialized). ``interpret=True`` makes it testable on CPU.
+- :func:`bin_sum_count_pallas` — a Pallas TPU kernel: a grid over row
+  chunks, one-hot partial products accumulated into the VMEM-resident
+  bucket table across the sequential steps (no HBM one-hot is ever
+  materialized). It covers at most ``MAX_BUCKETS`` buckets, so the
+  step's one-hot block stays within 1 MiB, and raises above that: a wider
+  table would re-read every row once per bucket tile, and nothing has
+  measured such a grid to win over scatter. ``interpret=True`` makes it
+  testable on CPU.
 
 Both compute per-bucket SUM and COUNT of float32 values in one pass.
 float32 only: the MXU has no 64-bit path — f64 aggregation keeps the
@@ -35,7 +39,11 @@ from typing import Any, Tuple
 
 import jax
 
-CHUNK = 1024  # rows per grid step; multiple of the f32 sublane tile (8)
+ROWS, LANES = 8, 128  # one grid step's rows, as one f32 (8, 128) tile
+CHUNK = ROWS * LANES  # rows per grid step
+# widest bucket table of the Pallas kernel: its one-hot block is
+# (MAX_BUCKETS, LANES) f32 = 1 MiB of VMEM
+MAX_BUCKETS = 2048
 
 
 def _pad_inputs(keys: Any, values: Any, valid: Any, buckets: int):
@@ -82,31 +90,31 @@ def bin_sum_count_xla(
     return ps.sum(axis=0), pc.sum(axis=0).astype(jnp.int32)
 
 
-def _bin_kernel(keys_ref, vals_ref, mask_ref, sums_ref, cnts_ref):
-    """One grid step: CHUNK rows → partial one-hot products accumulated
-    into the full (1, buckets) output block (same block every step, so
-    the accumulator lives in VMEM across the sequential TPU grid)."""
+def _bin_kernel(keys_ref, vals_ref, mask_ref, *out_refs):
+    """One grid step: CHUNK rows' one-hot partial products accumulated
+    into the ``(1, lanes)`` output blocks, which stay in VMEM across the
+    sequential row axis. The first output sums the values, the second (if
+    any) counts the valid rows."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        sums_ref[:, :] = jnp.zeros_like(sums_ref)
-        cnts_ref[:, :] = jnp.zeros_like(cnts_ref)
+        for o in out_refs:
+            o[:, :] = jnp.zeros_like(o)
 
-    buckets = sums_ref.shape[1]
-    k = keys_ref[0, :]  # (CHUNK,) int32
-    v = vals_ref[0, :]  # (CHUNK,) f32
-    m = mask_ref[0, :]  # (CHUNK,) f32
-    # 2D iota (1D iota does not lower on TPU)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, buckets), 1)
-    onehot = (k[:, None] == iota).astype(jnp.float32) * m[:, None]
-    sums_ref[:, :] += jnp.dot(
-        v[None, :], onehot, preferred_element_type=jnp.float32
-    )
-    cnts_ref[:, :] += jnp.dot(
-        m[None, :], onehot, preferred_element_type=jnp.float32
-    )
+    lanes = out_refs[0].shape[1]
+    # bucket id of each one-hot row (2D iota: 1D iota does not lower on TPU)
+    ids = jax.lax.broadcasted_iota(jnp.int32, (lanes, LANES), 0)
+    for r in range(ROWS):
+        k = keys_ref[pl.ds(r, 1), :]  # (1, LANES) int32
+        m = mask_ref[pl.ds(r, 1), :]  # (1, LANES) f32
+        onehot_t = (ids == k).astype(jnp.float32) * m  # (lanes, LANES)
+        for o, w in zip(out_refs, (vals_ref[pl.ds(r, 1), :], m)):
+            # (1, LANES) x (lanes, LANES)^T -> (1, lanes) on the MXU
+            o[:, :] += jax.lax.dot_general(
+                w, onehot_t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
 
 
 def bin_sum_idx(idx: Any, values: Any, buckets: int, backend: str) -> Any:
@@ -125,44 +133,30 @@ def bin_sum_idx(idx: Any, values: Any, buckets: int, backend: str) -> Any:
     return sums
 
 
-def _sum_kernel(keys_ref, vals_ref, mask_ref, sums_ref):
-    """Sum-only grid step (no count table — half the MXU work when the
-    caller doesn't need counts)."""
+def _pallas_binned(n_out: int, keys, values, valid, buckets, interpret):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        sums_ref[:, :] = jnp.zeros_like(sums_ref)
-
-    buckets = sums_ref.shape[1]
-    k = keys_ref[0, :]
-    v = vals_ref[0, :]
-    m = mask_ref[0, :]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, buckets), 1)
-    onehot = (k[:, None] == iota).astype(jnp.float32) * m[:, None]
-    sums_ref[:, :] += jnp.dot(
-        v[None, :], onehot, preferred_element_type=jnp.float32
-    )
-
-
-def _pallas_binned(kernel, n_out: int, keys, values, valid, buckets, interpret):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    # the accumulator's last dim must tile to the TPU's 128-lane registers
-    # — a BlockSpec over e.g. (1, 2) buckets fails or misbehaves on real
-    # hardware, so round the bucket table up and slice the result back
-    lanes = ((buckets + 127) // 128) * 128
+    if buckets > MAX_BUCKETS:
+        raise ValueError(
+            f"the pallas dense sum covers at most {MAX_BUCKETS} buckets, "
+            f"not {buckets}; use the scatter backend"
+        )
+    # the accumulator's last dim must tile to the TPU's 128-lane registers;
+    # round the bucket table up and slice the result back
+    lanes = ((buckets + LANES - 1) // LANES) * LANES
     keys, values, valid, n_chunks = _pad_inputs(keys, values, valid, buckets)
-    kc = keys.reshape(n_chunks, CHUNK)
-    vc = values.astype(jnp.float32).reshape(n_chunks, CHUNK)
-    mc = valid.astype(jnp.float32).reshape(n_chunks, CHUNK)
+    shape = (n_chunks * ROWS, LANES)
+    kc = keys.reshape(shape)
+    vc = values.astype(jnp.float32).reshape(shape)
+    mc = valid.astype(jnp.float32).reshape(shape)
 
-    row_spec = pl.BlockSpec((1, CHUNK), lambda i: (i, 0))
-    acc_spec = pl.BlockSpec((1, lanes), lambda i: (0, 0))
+    # block indices stay int32 (a literal 0 would be int64 under x64,
+    # which Mosaic refuses)
+    row_spec = pl.BlockSpec((ROWS, LANES), lambda i: (i, 0 * i))
+    acc_spec = pl.BlockSpec((1, lanes), lambda i: (0 * i, 0 * i))
     out = pl.pallas_call(
-        kernel,
+        _bin_kernel,
         grid=(n_chunks,),
         in_specs=[row_spec, row_spec, row_spec],
         out_specs=[acc_spec] * n_out,
@@ -176,7 +170,7 @@ def bin_sum_pallas(
     keys: Any, values: Any, valid: Any, buckets: int, interpret: bool = False
 ) -> Any:
     """Per-bucket SUM only (the dense-kernel hot path)."""
-    (sums,) = _pallas_binned(_sum_kernel, 1, keys, values, valid, buckets, interpret)
+    (sums,) = _pallas_binned(1, keys, values, valid, buckets, interpret)
     return sums[0]
 
 
@@ -188,7 +182,5 @@ def bin_sum_count_pallas(
     interpreter (CPU-testable)."""
     import jax.numpy as jnp
 
-    sums, cnts = _pallas_binned(
-        _bin_kernel, 2, keys, values, valid, buckets, interpret
-    )
+    sums, cnts = _pallas_binned(2, keys, values, valid, buckets, interpret)
     return sums[0], cnts[0].astype(jnp.int32)

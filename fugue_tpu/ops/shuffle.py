@@ -15,11 +15,13 @@ Protocol (static shapes throughout, SURVEY §7 "mask, don't branch"):
    ``(shards, capacity)`` send buffer, ``all_to_all``s the buffers, and
    returns the received rows + validity mask.
 
-Skew safety: when a hot destination pushes the block capacity past
-``SINGLE_ROUND_MAX_CAPACITY``, the exchange escalates to MULTIPLE bounded
-rounds (each moving ≤ that many rows per destination) that compact-append
-into output buffers sized by the true max received total — collective
-buffers and outputs stay O(data), never O(shards × hot-key count).
+Skew safety: when a hot destination pushes the block capacity past the
+round capacity (the pow2 of a shard's even share of its rows per
+destination, never below ``SINGLE_ROUND_MAX_CAPACITY``), the exchange
+escalates to MULTIPLE bounded rounds (each moving ≤ that many rows per
+destination) that compact-append into output buffers sized by the true max
+received total — collective buffers and outputs stay O(data), never
+O(shards × hot-key count), and a round count never passes the shard count.
 """
 
 from typing import Any, Dict, List, Optional, Tuple
@@ -28,7 +30,7 @@ import numpy as np
 
 from ..parallel.mesh import ROW_AXIS, num_row_shards
 from . import collectives
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 _COMPILE_CACHE: Dict[Any, Any] = {}
 
@@ -411,10 +413,36 @@ def compute_dest(
     raise ValueError(f"unknown shuffle algo {algo!r}")
 
 
-# single-round block capacity ceiling: a (shard, dest) pair needing more
-# rows than this escalates to the bounded multi-round exchange, whose peak
-# collective buffer stays shards × this regardless of key skew
+# floor of the round capacity: a (shard, dest) pair needing more rows than
+# the round capacity escalates to the bounded multi-round exchange, whose
+# peak collective buffer stays shards × the round capacity regardless of
+# key skew
 SINGLE_ROUND_MAX_CAPACITY = 1 << 17
+
+
+def exchange_plan(
+    max_count: int, local_rows: int, shards: int, limit: Optional[int] = None
+) -> Tuple[int, int]:
+    """``(block capacity, rounds)`` for an exchange whose largest
+    (shard, dest) block holds ``max_count`` rows, on shards of
+    ``local_rows`` rows. ``rounds == 1`` is the single all-to-all.
+
+    The round capacity is the pow2 of a shard's even share per destination
+    (``local_rows / shards``), never below ``limit``: balanced data moves
+    in one round whatever its size, and a skewed exchange runs at most
+    ``shards`` rounds, each with a send buffer of at most twice the
+    shard's rows. (A fixed capacity made a balanced 25M-row shard take 48
+    rounds, each re-reading the whole shard.)"""
+    floor = SINGLE_ROUND_MAX_CAPACITY if limit is None else limit
+    round_cap = max(_pow2_ceil(floor), _pow2_ceil(-(-local_rows // shards)))
+    capacity = _pow2_ceil(max_count)  # pow2 → reuse compiled variants
+    if capacity <= round_cap:
+        return capacity, 1
+    return round_cap, -(-max_count // round_cap)
+
+
+def _pow2_ceil(n: int) -> int:
+    return 1 << (max(1, int(n)) - 1).bit_length()
 
 
 def _get_compiled_lenmask(mesh: Any, out_cap: int):
@@ -454,22 +482,23 @@ def exchange_rows(
     Small/balanced exchanges run in ONE all-to-all with block capacity =
     the max per-(shard, dest) count (output local length shards ×
     capacity). Skewed exchanges — a hot destination pushing the block past
-    ``round_capacity`` — run MULTIPLE bounded rounds: each round moves at
-    most ``round_capacity`` rows per destination and compact-appends into
-    output buffers sized by the TRUE max received total, so neither the
-    collective buffers nor the output inflate with skew.
+    the round capacity of :func:`exchange_plan` (``round_capacity``
+    overrides its floor) — run at most ``shards`` bounded rounds: each
+    round moves at most the round capacity per destination and
+    compact-appends into output buffers sized by the TRUE max received
+    total, so neither the collective buffers nor the output inflate with
+    skew.
     """
     import jax
     import numpy as np_
 
     mx, total, mr = jax.device_get(_get_compiled_counts(mesh)(dest, valid))
-    cap = max(1, int(mx[0]))
-    capacity = 1 << (cap - 1).bit_length()  # pow2 → reuse compiled variants
-    limit = (
-        round_capacity if round_capacity is not None else SINGLE_ROUND_MAX_CAPACITY
+    shards = num_row_shards(mesh)
+    capacity, rounds = exchange_plan(
+        int(mx[0]), dest.shape[0] // shards, shards, round_capacity
     )
     dtypes = tuple(str(a.dtype) for a in arrays.values())
-    if capacity <= limit:
+    if rounds == 1:
         compiled = _get_compiled_exchange(mesh, dtypes, capacity)
         outs = compiled(dest, valid, *arrays.values())
         new_valid = outs[0]
@@ -478,10 +507,7 @@ def exchange_rows(
     # ---- multi-round path -------------------------------------------------
     from ..parallel.mesh import row_sharding
 
-    shards = num_row_shards(mesh)
-    round_cap = 1 << (max(1, limit) - 1).bit_length()
-    rounds = -(-cap // round_cap)  # ceil
-    out_cap = 1 << (max(1, int(mr[0])) - 1).bit_length()
+    out_cap = _pow2_ceil(int(mr[0]))
     sharding = row_sharding(mesh)
     rank = _get_compiled_rank(mesh)(dest, valid)
     out_len = jax.device_put(
@@ -493,7 +519,7 @@ def exchange_rows(
         )
         for a in arrays.values()
     ]
-    step = _get_compiled_round(mesh, dtypes, round_cap, out_cap)
+    step = _get_compiled_round(mesh, dtypes, capacity, out_cap)
     for r in range(rounds):
         outs = step(
             dest,

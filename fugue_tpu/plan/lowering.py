@@ -19,8 +19,7 @@ Execution is engine-mediated via ``engine.lowered_segment``:
   any lowering ineligibility on the jax engine degrades per segment to
   this path;
 - the jax engine compiles eligible segments into a single
-  ``shard_map``-partitioned jitted XLA program over the mesh (via the
-  ``_utils/jax_compat.py`` shim): the chain's Kleene-AND predicate and
+  ``shard_map``-partitioned jitted XLA program over the mesh: the chain's Kleene-AND predicate and
   projections evaluate on device and feed straight into the dense-bucket
   aggregate kernel, whose cross-shard combine is an in-program collective
   (``psum``/``pmin``/``pmax`` — ``ops/segment.py``). Streaming inputs

@@ -886,6 +886,10 @@ class EngineServer:
                     # so a cross-replica waiter re-decides (executes) rather
                     # than caching a failure fleet-wide
                     self._fleet.release(ex.key)
+        # a finished execution keeps its result, not its workflow: the
+        # workflow's context holds every intermediate frame, and retained
+        # submissions would keep those on the device
+        ex.dag = None
         if ex.state == "done":
             self._stats.inc("completed")
         else:

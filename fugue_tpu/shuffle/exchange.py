@@ -43,7 +43,7 @@ from ..ops.shuffle import (
     _get_compiled_lenmask,
     compute_dest,
 )
-from .._utils.jax_compat import shard_map
+from jax import shard_map
 
 __all__ = [
     "stage_capacity_rows",
@@ -142,34 +142,45 @@ def _stage_body(
     send_valid = lax.iota(jnp.int32, cap) < cnt
     # pack the stage into ONE contiguous byte payload — the validity lane
     # plus every array's window slice bitcast to bytes — so each stage is
-    # exactly ONE collective. Per-collective sync dominates a stage on
-    # mesh backends; per-array ppermutes multiplied that by the column
-    # count. The payload is cap × row_bytes: the exact quantity
-    # ``stage_capacity_rows`` budgets and ``peak_exchange`` records.
+    # one collective. Per-collective sync dominates a stage on mesh
+    # backends; per-array ppermutes multiplied that by the column count.
+    # float64 columns travel in a second payload of their own: v5e
+    # emulates float64 and cannot bitcast it. The payloads total
+    # cap × row_bytes: the exact quantity ``stage_capacity_rows`` budgets
+    # and ``peak_exchange`` records.
     lanes = [send_valid.astype(jnp.uint8)]
+    doubles = []
     for a in sarrs:
         send = lax.dynamic_slice_in_dim(a, start, cap)
-        if np.dtype(a.dtype).itemsize == 1:
+        if a.dtype == jnp.float64:
+            doubles.append(send)
+        elif np.dtype(a.dtype).itemsize == 1:
             lanes.append(send.astype(jnp.uint8))
         else:
             lanes.append(lax.bitcast_convert_type(send, jnp.uint8).reshape(-1))
     recv = collectives.ppermute(jnp.concatenate(lanes), ROW_AXIS, k)
+    if doubles:
+        recv_d = collectives.ppermute(jnp.concatenate(doubles), ROW_AXIS, k)
     recv_valid = recv[:cap].astype(bool)
     cum = jnp.cumsum(recv_valid.astype(jnp.int32))
     pos = out_len[0] + cum - 1
     idx = jnp.where(recv_valid, pos, out_cap)
     new_bufs = []
-    off = cap
+    off, off_d = cap, 0
     for a, buf in zip(sarrs, bufs):
         itemsize = np.dtype(a.dtype).itemsize
-        chunk = recv[off : off + cap * itemsize]
-        off += cap * itemsize
-        if itemsize == 1:
-            got = chunk.astype(a.dtype)
+        if a.dtype == jnp.float64:
+            got = recv_d[off_d : off_d + cap]
+            off_d += cap
         else:
-            got = lax.bitcast_convert_type(
-                chunk.reshape(cap, itemsize), a.dtype
-            )
+            chunk = recv[off : off + cap * itemsize]
+            off += cap * itemsize
+            if itemsize == 1:
+                got = chunk.astype(a.dtype)
+            else:
+                got = lax.bitcast_convert_type(
+                    chunk.reshape(cap, itemsize), a.dtype
+                )
         new_bufs.append(buf.at[idx].set(got, mode="drop"))
     new_len = out_len[0] + cum[-1]
     return new_len[None], new_bufs

@@ -22,7 +22,7 @@ The checklist:
   in place to a full recompute from the source;
 - **persist / restart**: a delta-merged ``persist()`` publishes the
   MERGED artifact, so a later exact-match run on a FRESH engine takes
-  the whole-task disk hit (STATUS.md PR 9 note);
+  the whole-task disk hit;
 - **observability**: delta counters flatten onto a valid Prometheus
   exposition; ``explain()`` renders ``DELTA[k/n partitions]``.
 

@@ -15,8 +15,7 @@ import pytest
 # the baked-in jaxlib cannot run cross-process collectives on the CPU
 # backend ("Multiprocess computations aren't implemented on the CPU
 # backend") — these tests pass on jax builds with the CPU collectives
-# (gloo) plugin and on real multi-host TPU meshes. Triage: STATUS.md
-# (tier-1 carried failures).
+# (gloo) plugin and on real multi-host TPU meshes.
 pytestmark = pytest.mark.xfail(
     reason=(
         "baked-in jaxlib lacks CPU-backend multiprocess collectives; "

@@ -222,3 +222,24 @@ def test_pool_wall_time_shrinks_with_workers():
     assert pooled < serial * 0.6, (serial, pooled)
     more = run(8)
     assert more < serial * 0.45, (serial, more)
+
+
+def test_no_fork_beside_libtpu(monkeypatch):
+    """A process holding the TPU runtime runs the pool's work serially:
+    forked children of libtpu crash in its signal handler."""
+    from fugue_tpu.execution import parallel_map
+
+    monkeypatch.setattr(parallel_map, "_holds_libtpu", lambda: True)
+    assert not parallel_map.fork_available()
+
+    def no_pool(*args, **kwargs):
+        raise AssertionError("forked beside libtpu")
+
+    monkeypatch.setattr(parallel_map, "_make_pool", no_pool)
+    df = pd.DataFrame({"k": np.arange(2000) % 7, "v": np.arange(2000) * 1.0})
+    out = fa.transform(
+        df, _demean, schema="k:long,v:double,d:double",
+        partition={"by": ["k"]}, engine="native", engine_conf=PAR_CONF,
+        as_local=True,
+    )
+    assert abs(pd.DataFrame(out).groupby("k")["d"].sum()).max() < 1e-9

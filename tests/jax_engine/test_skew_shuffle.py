@@ -95,3 +95,23 @@ def test_multiround_even_repartition(engine, monkeypatch):
     flt = engine.filter(jdf, col("v") < lit(1000.0))
     out = engine.repartition(flt, PartitionSpec(algo="even", num=8))
     assert sorted(out.as_pandas()["v"]) == sorted(range(1000))
+
+
+@pytest.mark.parametrize(
+    "max_count,local_rows,shards,plan",
+    [
+        # BASELINE config #3 on 4 chips: 25M rows a shard, 100,000 keys
+        # hashed evenly — one all-to-all, not 48 rounds of 2**17
+        (6_251_234, 25_000_000, 4, (1 << 23, 1)),
+        # every row to one shard: at most ``shards`` rounds
+        (25_000_000, 25_000_000, 4, (1 << 23, 3)),
+        (1 << 25, 1 << 25, 8, (1 << 22, 8)),
+        # small shards keep the floor
+        (300, 4_000, 8, (512, 1)),
+        (100_000, 100_000, 8, (1 << 17, 1)),
+    ],
+)
+def test_exchange_plan_bounds_rounds(max_count, local_rows, shards, plan):
+    assert S.exchange_plan(max_count, local_rows, shards) == plan
+    _, rounds = plan
+    assert rounds <= shards

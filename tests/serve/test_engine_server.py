@@ -439,3 +439,50 @@ def test_stats_endpoint_carries_serve_section(http_serve):
     assert code == 200
     assert st["serve"]["completed"] >= 1
     assert st["serve"]["queue_capacity"] == 2
+
+
+def test_finished_submissions_release_device_frames():
+    """A retained execution keeps its result, not its workflow's
+    intermediate frames: four served joins leave the device as they found
+    it (before the fix, each kept its joined frame alive — about 4x the
+    fact table here, and an out-of-memory on the chip at 100M rows)."""
+    import gc
+
+    import jax
+    import numpy as np
+
+    from fugue_tpu.jax import JaxExecutionEngine
+
+    def live() -> int:
+        gc.collect()
+        return sum(a.nbytes for a in jax.live_arrays())
+
+    eng = JaxExecutionEngine({"fugue.tpu.cache.enabled": False})
+    rng = np.random.default_rng(0)
+    n = 50_000
+    fact = eng.persist(
+        eng.to_df(pd.DataFrame({"k": rng.integers(0, 100, n), "v": rng.random(n)}))
+    )
+    dim = eng.persist(
+        eng.to_df(pd.DataFrame({"k": np.arange(100), "w": rng.random(100)}))
+    )
+
+    def factory() -> FugueWorkflow:
+        dag = FugueWorkflow()
+        (
+            dag.df(fact)
+            .inner_join(dag.df(dim), on=["k"])
+            .select(col("k"), (col("v") * col("w")).alias("vw"))
+            .partition_by("k")
+            .aggregate(ff.sum(col("vw")).alias("s"))
+            .yield_dataframe_as("r", as_local=True)
+        )
+        return dag
+
+    before = live()
+    with EngineServer(eng) as srv:
+        subs = [srv.submit(factory, tenant=f"t{i % 2}") for i in range(4)]
+        for s in subs:
+            assert len(s.result(timeout=120).yields["r"].result.as_pandas()) == 100
+        assert live() - before < n * 16 // 4
+    eng.stop()
