@@ -44,7 +44,7 @@ MODULES = [
     ("fugue_tpu.views", "Continuous views (registry, watch leases, maintainer)"),
     ("fugue_tpu.dist", "Multi-host worker tier (leases, heartbeats, supervisor)"),
     ("fugue_tpu.obs", "Observability (tracer, cluster traces, flight recorder, metrics)"),
-    ("fugue_tpu.tuning", "Adaptive tuning (learned settings, verb rooflines)"),
+    ("fugue_tpu.tuning", "Adaptive tuning (learned settings)"),
     ("fugue_tpu.analysis", "UDF static analyzer (AST trace, translation, lint)"),
     ("fugue_tpu.test", "Test harness plugins (fugue_test_suite/with_backend)"),
     ("fugue_tpu.notebook", "Notebook %%fsql magic"),

@@ -453,13 +453,6 @@ FUGUE_TPU_CONF_TUNING_PATH = "fugue.tpu.tuning.path"
 # plan-fingerprint entries kept in the store; least-recently-used past it
 # are evicted at publish time (stale-plan hygiene for long-lived servers)
 FUGUE_TPU_CONF_TUNING_MAX_ENTRIES = "fugue.tpu.tuning.max_entries"
-# per-verb roofline recording (ISSUE 18, ROADMAP item 5 groundwork):
-# while tracing is enabled the jax engine folds each verb's achieved
-# bytes/s and rows/s into the TunedStore's "rooflines" key (same atomic
-# publish + LRU bounds), rendered by engine.report(). Record-only — no
-# placement decision reads it yet. Default ON (cost: one in-memory fold
-# per traced verb close; nothing at all while tracing is off).
-FUGUE_TPU_CONF_TUNING_ROOFLINES = "fugue.tpu.tuning.rooflines"
 
 FUGUE_COMPILE_TIME_CONFIGS = {
     FUGUE_CONF_WORKFLOW_AUTO_PERSIST,

@@ -507,19 +507,11 @@ class ExecutionEngine(FugueEngineBase):
         histograms — plus this engine's metrics."""
         from ..obs import get_span_metrics, get_tracer, render_report
 
-        rooflines = None
-        tuner = getattr(self, "_tuner", None)  # never force lazy creation
-        if tuner is not None:
-            try:
-                rooflines = tuner.roofline.snapshot() or None
-            except Exception:
-                rooflines = None
         return render_report(
             get_tracer().records(),
             self.stats(),
             top_n=top_n,
             span_metrics=get_span_metrics(),
-            rooflines=rooflines,
         )
 
     @property

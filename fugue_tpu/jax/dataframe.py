@@ -29,6 +29,7 @@ from .._utils.assertion import assert_or_throw
 from ..dataframe import ArrowDataFrame, DataFrame, LocalBoundedDataFrame
 from ..dataframe.arrow_dataframe import build_arrow_table
 from ..exceptions import FugueDataFrameInitError, FugueDataFrameOperationError
+from ..obs.tracer import NULL_SPAN, get_tracer
 from ..parallel.mesh import ROW_AXIS, num_row_shards, pad_rows, row_sharding
 from ..schema import Schema
 
@@ -340,6 +341,27 @@ class JaxDataFrame(DataFrame):
             self._pending_src = None
 
     def _from_arrow(self, tbl: pa.Table) -> None:
+        """Encode ``tbl`` and put its columns on the device: one
+        ``frame.ingest`` span (the transfers are issued, not awaited)."""
+        with get_tracer().span("frame.ingest", annotate=True) as sp:
+            self._put_arrow(tbl)
+            if sp is not NULL_SPAN:
+                sp.set(
+                    rows=tbl.num_rows,
+                    columns=tbl.num_columns,
+                    bytes=self._device_nbytes(self._device_cols),
+                )
+
+    def _device_nbytes(self, names: Iterable[str]) -> int:
+        """Device bytes of the columns ``names`` with their null masks, and
+        of the validity mask."""
+        arrs = [self._device_cols[n] for n in names]
+        arrs += [self._null_masks[n] for n in names if n in self._null_masks]
+        if self._valid_mask is not None:
+            arrs.append(self._valid_mask)
+        return int(sum(a.nbytes for a in arrs))
+
+    def _put_arrow(self, tbl: pa.Table) -> None:
         import jax
 
         n = tbl.num_rows
@@ -675,8 +697,6 @@ class JaxDataFrame(DataFrame):
         return arr.cast(f.type, safe=False)
 
     def as_arrow(self, type_safe: bool = False) -> pa.Table:
-        import jax
-
         pend = self._pending_snapshot()
         if pend is not None:
             # never ingested: the arrow table IS the data — but the device
@@ -685,6 +705,22 @@ class JaxDataFrame(DataFrame):
         src = getattr(self, "_ingest_tbl", None)
         if src is not None:
             return src
+        with get_tracer().span("frame.fetch", annotate=True) as sp:
+            out = self._fetch_arrow()
+            if sp is not NULL_SPAN:
+                sp.set(
+                    rows=out.num_rows,
+                    columns=out.num_columns,
+                    bytes=self._device_nbytes(
+                        [n for n in self.schema.names if n in self._device_cols]
+                    ),
+                )
+        return out
+
+    def _fetch_arrow(self) -> pa.Table:
+        """Read the device columns back and decode them (the valid rows)."""
+        import jax
+
         mask: Optional[np.ndarray] = None
         if self._valid_mask is not None:
             mask = np.asarray(jax.device_get(self._valid_mask))

@@ -54,7 +54,7 @@ from ..parallel.mesh import (
 )
 from ..schema import Schema
 from .dataframe import JaxDataFrame, _DEVICE_DTYPES
-from ..obs import traced_verb
+from ..obs import NULL_SPAN, get_host_op_stats, get_tracer, traced_verb
 from jax import shard_map
 
 
@@ -654,6 +654,8 @@ class JaxExecutionEngine(ExecutionEngine):
         self.metrics.register("pipeline", lambda: self._pipeline_stats)
         self.metrics.register("jit_cache", lambda: self._jit_cache)
         self.metrics.register("shuffle", lambda: self._shuffle_stats)
+        # host fallbacks, fetches and ingests, counted from their spans
+        self.metrics.register("host_ops", get_host_op_stats())
         # record the resolved device budget + which detection source won
         # (conf / device_memory_stats / host_meminfo / fallback) so a
         # mis-detected budget is visible in engine.stats()["shuffle"]
@@ -664,13 +666,6 @@ class JaxExecutionEngine(ExecutionEngine):
             self._shuffle_stats.set_budget(_budget, _budget_src)
         except Exception:
             pass
-        # per-verb roofline recording (ISSUE 18, record-only): while
-        # tracing is enabled, every traced verb's close folds achieved
-        # bytes/s + rows/s into this engine's tuner (TunedStore
-        # "rooflines" key); fugue.tpu.tuning.rooflines=false opts out
-        from ..tuning import install_verb_observer
-
-        install_verb_observer(self)
 
     def _resource_probe_fns(self) -> Dict[str, Any]:
         # jax-engine occupancy for the continuous resource sampler
@@ -954,11 +949,13 @@ class JaxExecutionEngine(ExecutionEngine):
         from ..column.jax_eval import device_predicate_plan
 
         jdf = self.to_df(df)
+        reason = "host_frame"
         if (
             isinstance(jdf, JaxDataFrame)
             and len(jdf.device_cols) > 0
             and jdf.host_table is None
         ):
+            reason = "where_not_lowered"
             plan = (
                 _plan
                 if _plan is not None
@@ -1020,7 +1017,8 @@ class JaxExecutionEngine(ExecutionEngine):
                         schema=jdf.schema,
                     ),
                 )
-        return self._back(self._host_engine.filter(self._host(df), condition))
+        with _host_op("filter", reason) as sp:
+            return self._back(self._host_engine.filter(self._host(df, sp), condition))
 
     @traced_verb("engine.fused")
     def fused_apply(self, df: DataFrame, steps: Any) -> DataFrame:
@@ -1399,9 +1397,9 @@ class JaxExecutionEngine(ExecutionEngine):
             jdf0 = JaxDataFrame(ArrowDataFrame(empty), mesh=self._mesh)
         except Exception:
             return None
-        plan = _plan_device_agg(jdf0, keys, agg_cols)
+        plan = _device_agg_plan(jdf0, keys, agg_cols)
         if (
-            plan is None
+            isinstance(plan, str)
             or plan["virtual"]
             or plan["dict_srcs"]
             or plan["masked_srcs"]
@@ -1561,8 +1559,13 @@ class JaxExecutionEngine(ExecutionEngine):
 
         return run
 
-    def _host(self, df: DataFrame) -> DataFrame:
-        return df.as_local_bounded() if isinstance(df, JaxDataFrame) else self._host_engine.to_df(df)
+    def _host(self, df: DataFrame, sp: Any = NULL_SPAN) -> DataFrame:
+        """The host copy of ``df`` for a host-engine call; inside a live
+        ``host.op`` span ``sp`` its rows count toward ``rows_in``."""
+        local = df.as_local_bounded() if isinstance(df, JaxDataFrame) else self._host_engine.to_df(df)
+        if sp is not NULL_SPAN:
+            sp.add(rows_in=local.count())
+        return local
 
     def _back(self, df: DataFrame) -> DataFrame:
         return self.to_df(df)
@@ -1717,7 +1720,10 @@ class JaxExecutionEngine(ExecutionEngine):
                 sp.set(strategy="broadcast")
                 return res
         sp.set(strategy="host")
-        return self._back(self._host_engine.join(self._host(df1), self._host(df2), how=how, on=on))
+        with _host_op("join", "device_join_refused", jt) as hp:
+            return self._back(
+                self._host_engine.join(self._host(df1, hp), self._host(df2, hp), how=how, on=on)
+            )
 
     def _try_device_exchange(self, df1, df2, how: str, on, tune) -> Optional[DataFrame]:
         """Run the join through the device_exchange rung: the same device
@@ -2917,9 +2923,10 @@ class JaxExecutionEngine(ExecutionEngine):
                 ),
             )
             return self.distinct(res) if distinct else res
-        return self._back(
-            self._host_engine.union(self._host(df1), self._host(df2), distinct=distinct)
-        )
+        with _host_op("union", "setop_not_lowered") as sp:
+            return self._back(
+                self._host_engine.union(self._host(df1, sp), self._host(df2, sp), distinct=distinct)
+            )
 
     def _false_mask_like(self, jdf: JaxDataFrame) -> Any:
         """An all-False device bool array row-aligned with the frame."""
@@ -2960,9 +2967,10 @@ class JaxExecutionEngine(ExecutionEngine):
             )
             if res is not None:
                 return res
-        return self._back(
-            self._host_engine.subtract(self._host(df1), self._host(df2), distinct=distinct)
-        )
+        with _host_op("subtract", "setop_not_lowered") as sp:
+            return self._back(
+                self._host_engine.subtract(self._host(df1, sp), self._host(df2, sp), distinct=distinct)
+            )
 
     @traced_verb("engine.intersect")
     def intersect(self, df1, df2, distinct: bool = True) -> DataFrame:
@@ -2975,9 +2983,10 @@ class JaxExecutionEngine(ExecutionEngine):
             )
             if res is not None:
                 return res
-        return self._back(
-            self._host_engine.intersect(self._host(df1), self._host(df2), distinct=distinct)
-        )
+        with _host_op("intersect", "setop_not_lowered") as sp:
+            return self._back(
+                self._host_engine.intersect(self._host(df1, sp), self._host(df2, sp), distinct=distinct)
+            )
 
     def _group_key_cols(self, jdf: JaxDataFrame, names: List[str]) -> Any:
         """(key_cols_for_kernel, mask_col_names) — nullable columns add
@@ -3093,13 +3102,15 @@ class JaxExecutionEngine(ExecutionEngine):
                 )
             except PartialsTooLarge:
                 # near-unique rows: the O(groups) transfer stops paying off
-                return self._back(self._host_engine.distinct(self._host(df)))
+                with _host_op("distinct", "partials_too_large") as sp:
+                    return self._back(self._host_engine.distinct(self._host(df, sp)))
             res = partials.drop(columns=[count_name]).drop_duplicates(
                 ignore_index=True
             )
             res = self._decode_partial_keys(jdf, res, mask_names)
             return self.to_df(PandasDataFrame(res[jdf.schema.names], jdf.schema))
-        return self._back(self._host_engine.distinct(self._host(df)))
+        with _host_op("distinct", "host_frame") as sp:
+            return self._back(self._host_engine.distinct(self._host(df, sp)))
 
     @traced_verb("engine.dropna")
     def dropna(self, df, how="any", thresh=None, subset=None) -> DataFrame:
@@ -3169,9 +3180,10 @@ class JaxExecutionEngine(ExecutionEngine):
                     schema=jdf.schema,
                 ),
             )
-        return self._back(
-            self._host_engine.dropna(self._host(df), how=how, thresh=thresh, subset=subset)
-        )
+        with _host_op("dropna", "not_lowered") as sp:
+            return self._back(
+                self._host_engine.dropna(self._host(df, sp), how=how, thresh=thresh, subset=subset)
+            )
 
     @traced_verb("engine.fillna")
     def fillna(self, df, value, subset=None) -> DataFrame:
@@ -3195,9 +3207,10 @@ class JaxExecutionEngine(ExecutionEngine):
             else:
                 fills = {c: value for c in (subset or jdf.schema.names)}
             if any(c in jdf.encodings for c in fills):
-                return self._back(
-                    self._host_engine.fillna(self._host(df), value, subset=subset)
-                )
+                with _host_op("fillna", "encoded_column") as sp:
+                    return self._back(
+                        self._host_engine.fillna(self._host(df, sp), value, subset=subset)
+                    )
             masked_fills = frozenset(c for c in fills if c in jdf.null_masks)
             fill_sig = tuple(sorted((k, float(v)) for k, v in fills.items() if k in jdf.schema))
             key = ("fillna", fill_sig, tuple(jdf.schema.names), masked_fills)
@@ -3250,7 +3263,8 @@ class JaxExecutionEngine(ExecutionEngine):
                     schema=jdf.schema,
                 ),
             )
-        return self._back(self._host_engine.fillna(self._host(df), value, subset=subset))
+        with _host_op("fillna", "host_frame") as sp:
+            return self._back(self._host_engine.fillna(self._host(df, sp), value, subset=subset))
 
     @traced_verb("engine.sample")
     def sample(self, df, n=None, frac=None, replace=False, seed=None) -> DataFrame:
@@ -3297,9 +3311,10 @@ class JaxExecutionEngine(ExecutionEngine):
                     schema=jdf.schema,
                 ),
             )
-        return self._back(
-            self._host_engine.sample(self._host(df), n=n, frac=frac, replace=replace, seed=seed)
-        )
+        with _host_op("sample", "not_lowered") as sp:
+            return self._back(
+                self._host_engine.sample(self._host(df, sp), n=n, frac=frac, replace=replace, seed=seed)
+            )
 
     @traced_verb("engine.take")
     def take(self, df, n, presort, na_position="last", partition_spec=None) -> DataFrame:
@@ -3456,16 +3471,19 @@ class JaxExecutionEngine(ExecutionEngine):
                         pdf[jdf.schema.names].reset_index(drop=True), jdf.schema
                     )
                 )
-        return self._back(
-            self._host_engine.take(
-                self._host(df), n, presort, na_position=na_position, partition_spec=partition_spec
+        with _host_op("take", "not_lowered") as sp:
+            return self._back(
+                self._host_engine.take(
+                    self._host(df, sp), n, presort, na_position=na_position, partition_spec=partition_spec
+                )
             )
-        )
 
     def load_df(self, path, format_hint=None, columns=None, **kwargs) -> DataFrame:
-        return self.to_df(
-            self._host_engine.load_df(path, format_hint=format_hint, columns=columns, **kwargs)
-        )
+        with get_tracer().span("io.read", annotate=True, format=format_hint) as sp:
+            local = self._host_engine.load_df(path, format_hint=format_hint, columns=columns, **kwargs)
+            if sp is not NULL_SPAN:
+                sp.set(rows=local.count(), bytes=_host_bytes(local))
+        return self.to_df(local)
 
     def save_df(
         self, df, path, format_hint=None, mode="overwrite",
@@ -3506,6 +3524,7 @@ class JaxExecutionEngine(ExecutionEngine):
                 jdf = self.filter(jdf, where, _plan=where_plan)  # type: ignore
                 where = None
         # grouped aggregation lowers to the device groupby
+        agg_refused: Optional[str] = None
         if where is None and sc.has_agg and not sc.is_distinct:
             from ..collections.partition import PartitionSpec as _PSpec
             from ..column.expressions import _NamedColumnExpr as _Named
@@ -3513,15 +3532,17 @@ class JaxExecutionEngine(ExecutionEngine):
 
             keys = [c for c in sc.all_cols if not _is_agg(c)]
             aggs = [c for c in sc.all_cols if _is_agg(c)]
-            if (
-                len(keys) > 0
-                and all(
-                    isinstance(k, _Named) and k.as_type is None and k.as_name == ""
-                    for k in keys
-                )
+            agg_refused = "no_keys"
+            if len(keys) > 0 and not all(
+                isinstance(k, _Named) and k.as_type is None and k.as_name == ""
+                for k in keys
             ):
+                agg_refused = "computed_keys"
+            elif len(keys) > 0:
                 spec = _PSpec(by=[k.name for k in keys])
-                if _plan_device_agg(jdf, spec.partition_by, aggs) is not None:
+                agg_plan = _device_agg_plan(jdf, spec.partition_by, aggs)
+                agg_refused = agg_plan if isinstance(agg_plan, str) else None
+                if agg_refused is None:
                     res = self.aggregate(jdf, spec, aggs)
                     if having is not None:
                         # the aggregate result is O(groups): host filter;
@@ -3529,12 +3550,13 @@ class JaxExecutionEngine(ExecutionEngine):
                         # output columns (same contract as the oracle)
                         from ..column.eval import rewrite_having_aggs
 
-                        res = self._back(
-                            self._host_engine.filter(
-                                self._host(res),
-                                rewrite_having_aggs(having, aggs),
+                        with _host_op("filter", "having_after_agg") as sp:
+                            res = self._back(
+                                self._host_engine.filter(
+                                    self._host(res, sp),
+                                    rewrite_having_aggs(having, aggs),
+                                )
                             )
-                        )
                     # restore declared projection order
                     order = [c.output_name for c in sc.all_cols]
                     if res.schema.names != order:
@@ -3558,11 +3580,12 @@ class JaxExecutionEngine(ExecutionEngine):
             )
         ):
             return self._device_project(jdf, sc)
-        return self._back(
-            self._host_engine.select(
-                self._host(jdf), cols, where=where, having=having
+        with _host_op("select", _select_refusal, jdf, sc, where, having, agg_refused) as sp:
+            return self._back(
+                self._host_engine.select(
+                    self._host(jdf, sp), cols, where=where, having=having
+                )
             )
-        )
 
     def _device_project(self, jdf: JaxDataFrame, sc: SelectColumns) -> DataFrame:
         import jax
@@ -3880,11 +3903,12 @@ class JaxExecutionEngine(ExecutionEngine):
             )
         jdf = self.to_df(df)
         keys = list(partition_spec.partition_by) if partition_spec is not None else []
-        plan = _plan_device_agg(jdf, keys, agg_cols)
-        if plan is None or len(keys) == 0:
-            return self._back(
-                self._host_engine.aggregate(self._host(df), partition_spec, agg_cols)
-            )
+        plan = _device_agg_plan(jdf, keys, agg_cols)
+        if isinstance(plan, str):
+            with _host_op("aggregate", "agg_plan_refused", plan) as sp:
+                return self._back(
+                    self._host_engine.aggregate(self._host(df, sp), partition_spec, agg_cols)
+                )
         # dict codes / epoch ints group by device identity; nullable keys add
         # their mask as an extra key so NULL is its own group
         key_cols, mask_names = self._group_key_cols(jdf, keys)
@@ -4135,15 +4159,66 @@ def _is_passthrough(c: ColumnExpr, device_cols: Any) -> bool:
     )
 
 
-def _plan_device_agg(
+def _host_op(op: str, reason: Any, *why: Any) -> Any:
+    """The ``host.op`` span around one fallback of a verb into the host
+    engine; ``JaxExecutionEngine._host`` adds the rows it fetches to
+    ``rows_in``. ``reason`` names why the device path refused: a token,
+    joined with ``why`` as ``token:why``, or a function of ``why`` that
+    builds one. Nothing is built while tracing is off."""
+    tr = get_tracer()
+    if not tr.enabled:
+        return NULL_SPAN
+    if callable(reason):
+        reason = reason(*why)
+    elif why:
+        reason = ":".join([reason, *map(str, why)])
+    return tr.span("host.op", annotate=True, op=op, reason=reason, rows_in=0)
+
+
+def _select_refusal(
+    jdf: JaxDataFrame,
+    sc: SelectColumns,
+    where: Optional[ColumnExpr],
+    having: Optional[ColumnExpr],
+    agg_refused: Optional[str],
+) -> str:
+    """Why ``select`` runs on the host: the first device path that refused."""
+    if len(jdf.device_cols) == 0:
+        return "host_frame"
+    if where is not None:
+        return "where_not_lowered"
+    if agg_refused is not None:
+        return f"agg_plan_refused:{agg_refused}"
+    if sc.is_distinct:
+        return "distinct"
+    if having is not None:
+        return "having"
+    return "projection_not_lowered"
+
+
+def _host_bytes(df: DataFrame) -> int:
+    """The in-memory size of a host frame, as its arrow or pandas form
+    reports it (0 where neither is at hand)."""
+    native = df.native
+    if isinstance(native, pa.Table):
+        return int(native.nbytes)
+    if isinstance(native, pd.DataFrame):
+        return int(native.memory_usage(index=False).sum())
+    return 0
+
+
+def _device_agg_plan(
     jdf: JaxDataFrame, keys: List[str], agg_cols: List[ColumnExpr]
-) -> Optional[dict]:
-    """Build the device-aggregation plan or None if not device-compatible."""
+) -> Union[dict, str]:
+    """The device-aggregation plan, or a short token naming why the device
+    aggregate refuses (the ``reason`` of a ``host.op`` span)."""
     from ..column.expressions import _FuncExpr, _NamedColumnExpr
     from ..column import functions as ff
 
-    if len(keys) == 0 or not all(k in jdf.device_cols for k in keys):
-        return None
+    if len(keys) == 0:
+        return "no_keys"
+    if not all(k in jdf.device_cols for k in keys):
+        return "key_not_on_device"
     aggs: List[Any] = []
     post: List[dict] = []
     virtual: Dict[str, Any] = {}  # vname -> (tag, real src)
@@ -4154,9 +4229,9 @@ def _plan_device_agg(
 
     for c in agg_cols:
         if not isinstance(c, _FuncExpr) or not c.is_agg or c.is_distinct:
-            return None
+            return "agg_not_plain"
         if len(c.args) != 1:
-            return None
+            return "agg_args"
         if c.func.upper() == "COUNT" and (
             (
                 isinstance(c.args[0], _LitColumnExpr)
@@ -4171,7 +4246,7 @@ def _plan_device_agg(
             # included — a ones column summed under the validity mask
             name = c.output_name
             if name == "":
-                return None
+                return "agg_unnamed"
             virtual["__ones__"] = ("ones", None)
             aggs.append((name, "sum", "__ones__"))
             post.append(
@@ -4181,11 +4256,11 @@ def _plan_device_agg(
             fields.append(pa.field(name, tp if tp is not None else pa.int64()))
             continue
         if not isinstance(c.args[0], _NamedColumnExpr):
-            return None
+            return "agg_of_expression"
         src = c.args[0].name
         func = c.func.upper()
         if src not in jdf.device_cols:
-            return None
+            return "value_not_on_device"
         enc = jdf.encodings.get(src)
         if enc is not None:
             # sorted-dictionary strings: code order == value order, so
@@ -4196,7 +4271,7 @@ def _plan_device_agg(
                 and enc.get("sorted")
                 and func in ("MIN", "MAX", "COUNT")
             ):
-                return None
+                return "encoded_value"
             dict_srcs.add(src)
         big_int_masked = False
         if src in jdf.null_masks:
@@ -4206,7 +4281,7 @@ def _plan_device_agg(
             if dt.kind == "u" and dt.itemsize >= 8:
                 # uint64 > 2^63 has no faithful pandas/post-processing
                 # representation here — host engine computes it exactly
-                return None
+                return "uint64_with_nulls"
             if dt.kind == "i" and dt.itemsize >= 8:
                 # int64 with NULLs: the float64 NaN view loses exactness
                 # past 2^53 — SUM/AVG split into hi/lo 32-bit halves
@@ -4217,11 +4292,11 @@ def _plan_device_agg(
                 masked_srcs.add(src)
         name = c.output_name
         if name == "":
-            return None
+            return "agg_unnamed"
         tp = c.infer_type(jdf.schema)
         if big_int_masked:
             if func not in ("SUM", "AVG", "MIN", "MAX", "COUNT"):
-                return None
+                return "agg_func"
             nn = f"{name}__nn"
             if func in ("SUM", "AVG"):
                 virtual[f"{src}__hi__"] = ("hi", src)
@@ -4319,7 +4394,7 @@ def _plan_device_agg(
                 }
             )
         else:
-            return None
+            return "agg_func"
         fields.append(pa.field(name, tp if tp is not None else pa.float64()))
     return {
         "aggs": aggs,

@@ -431,7 +431,7 @@ def streaming_dense_aggregate(
         dense_buckets,
     )
     from .dataframe import JaxDataFrame
-    from .execution_engine import _plan_device_agg
+    from .execution_engine import _device_agg_plan
 
     keys = list(partition_spec.partition_by) if partition_spec is not None else []
     if len(keys) != 1:
@@ -446,9 +446,9 @@ def streaming_dense_aggregate(
     # lose its head to a plan that then falls back to materialization
     empty = pa.Table.from_pylist([], schema=Schema(df.schema).pa_schema)
     jdf0 = JaxDataFrame(ArrowDataFrame(empty), mesh=mesh)
-    plan = _plan_device_agg(jdf0, keys, agg_cols)
+    plan = _device_agg_plan(jdf0, keys, agg_cols)
     if (
-        plan is None
+        isinstance(plan, str)
         or plan["virtual"]
         or plan["dict_srcs"]
         or plan["masked_srcs"]
@@ -782,7 +782,7 @@ def plan_streaming_lowered_aggregate(
     )
     from ..parallel.mesh import ROW_AXIS, num_row_shards, pad_rows
     from .dataframe import JaxDataFrame
-    from .execution_engine import _plan_device_agg
+    from .execution_engine import _device_agg_plan
 
     if len(keys) != 1 or len(steps) == 0:
         return None
@@ -795,9 +795,9 @@ def plan_streaming_lowered_aggregate(
         jdf0 = JaxDataFrame(ArrowDataFrame(empty), mesh=mesh)
     except Exception:
         return None
-    plan = _plan_device_agg(jdf0, keys, agg_cols)
+    plan = _device_agg_plan(jdf0, keys, agg_cols)
     if (
-        plan is None
+        isinstance(plan, str)
         or plan["virtual"]
         or plan["dict_srcs"]
         or plan["masked_srcs"]

@@ -31,6 +31,7 @@ from .export import (
     validate_chrome_trace,
     write_chrome_trace,
 )
+from .host_ops import HostOpStats, get_host_op_stats
 from .metrics import (
     Histogram,
     HistogramFamily,
@@ -56,7 +57,6 @@ from .tracer import (
     get_tracer,
     mint_trace_id,
     proc_ident,
-    set_verb_observer,
     trace_carrier,
     trace_scope,
     traced_verb,
@@ -67,6 +67,7 @@ __all__ = [
     "EventLog",
     "Histogram",
     "HistogramFamily",
+    "HostOpStats",
     "MetricsRegistry",
     "NULL_SPAN",
     "ResourceSampler",
@@ -80,6 +81,7 @@ __all__ = [
     "current_run_labels",
     "current_trace_id",
     "get_event_log",
+    "get_host_op_stats",
     "get_sampler",
     "get_span_metrics",
     "get_tracer",
@@ -91,7 +93,6 @@ __all__ = [
     "render_report",
     "render_timeline",
     "run_labels",
-    "set_verb_observer",
     "to_chrome_trace",
     "to_prometheus_text",
     "trace_carrier",

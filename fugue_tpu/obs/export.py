@@ -196,16 +196,12 @@ def render_report(
     stats: Optional[Dict[str, Any]] = None,
     top_n: int = 15,
     span_metrics: Any = None,
-    rooflines: Optional[Dict[str, Dict[str, Any]]] = None,
 ) -> str:
     """Plain-text top-N report: spans grouped by name with count / total /
     self / mean / p50 / p95 / p99 / max wall, plus the metrics registry
     dump. Quantiles come from the span-latency histograms (the global
     :class:`~fugue_tpu.obs.metrics.SpanMetrics` store unless one is
-    passed); a span name with no histogram series prints ``-``.
-    ``rooflines`` (``<verb>|<dtype-class>|w<width>`` → throughput fold,
-    the ISSUE 18 record-only table) renders as its own section when
-    non-empty."""
+    passed); a span name with no histogram series prints ``-``."""
     if span_metrics is None:
         from .metrics import get_span_metrics
 
@@ -251,33 +247,6 @@ def render_report(
                 f"{a['total'] / a['count'] / 1e6:>10.3f}"
                 f"{q(name, 'p50_ms')}{q(name, 'p95_ms')}{q(name, 'p99_ms')}"
                 f"{a['max'] / 1e6:>10.3f}"
-            )
-    if rooflines:
-        lines.append("")
-        lines.append("== verb rooflines (record-only; best achieved) ==")
-        lines.append(
-            f"{'verb|dtype|width':<36}{'obs':>6}{'best_MB/s':>12}"
-            f"{'best_Mrow/s':>13}{'last_MB/s':>12}{'last_Mrow/s':>13}"
-        )
-
-        def mb(v: Any) -> str:
-            return (
-                f"{float(v) / 1e6:>12.2f}"
-                if isinstance(v, (int, float))
-                else f"{'-':>12}"
-            )
-
-        ranked_rl = sorted(
-            rooflines.items(),
-            key=lambda kv: -float(kv[1].get("best_bytes_s", 0) or 0),
-        )
-        for key, e in ranked_rl:
-            lines.append(
-                f"{key:<36}{int(e.get('obs', 0) or 0):>6}"
-                f"{mb(e.get('best_bytes_s'))}"
-                f"{mb(e.get('best_rows_s')):>13}"
-                f"{mb(e.get('last_bytes_s'))}"
-                f"{mb(e.get('last_rows_s')):>13}"
             )
     if stats:
         lines.append("")

@@ -6,6 +6,17 @@ layer down to individual streaming chunks:
     workflow.run → workflow.task → engine.<verb> → stream.chunk
                                  → map.parallel → map.worker_chunk → map.partition
 
+A FugueSQL query adds ``sql.parse`` and ``plan.optimize`` beside
+``workflow.run``, and below the verbs:
+
+    workflow.task → io.read
+                  → engine.<verb> → frame.ingest
+                                  → host.op → frame.fetch
+                                  → device.wait
+
+These spans and ``workflow.*`` enter the XLA annotation, so an idle gap
+on the device trace falls inside a named span.
+
 Design constraints, in priority order:
 
 - **Near-zero overhead when disabled.** ``tracer.span(...)`` returns one
@@ -47,6 +58,7 @@ import uuid
 from contextvars import ContextVar
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from .host_ops import get_host_op_stats
 from .metrics import get_span_metrics
 
 __all__ = [
@@ -54,7 +66,6 @@ __all__ = [
     "get_tracer",
     "configure_from_conf",
     "traced_verb",
-    "set_verb_observer",
     "NULL_SPAN",
     "proc_ident",
     "mint_trace_id",
@@ -147,12 +158,16 @@ class _NullSpan:
     def set(self, **attrs: Any) -> None:
         pass
 
+    def add(self, **attrs: Any) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    """A live span: context manager + attribute sink (``sp.set(rows=...)``)."""
+    """A live span: context manager + attribute sink (``sp.set(rows=...)``,
+    ``sp.add(rows_in=...)``)."""
 
     __slots__ = ("_tr", "_name", "_cat", "_annotate", "_parent", "_args", "_sid", "_ann", "_t0")
 
@@ -197,6 +212,11 @@ class _SpanCtx:
 
     def set(self, **attrs: Any) -> None:
         self._args.update(attrs)
+
+    def add(self, **attrs: Any) -> None:
+        """Accumulate numeric attributes (``sp.add(rows_in=n)``)."""
+        for k, v in attrs.items():
+            self._args[k] = self._args.get(k, 0) + v
 
     def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
         t1 = time.perf_counter_ns()
@@ -268,6 +288,7 @@ class Tracer:
         # the buffer-cap check: distributions must stay correct even when
         # the span buffer saturates and drops the raw record
         get_span_metrics().observe_record(rec)
+        get_host_op_stats().observe_record(rec)
         with self._lock:
             if len(self._records) >= self.max_spans:
                 self.dropped += 1
@@ -429,25 +450,9 @@ def configure_from_conf(conf: Any) -> None:
         tr.max_spans = int(cap)
 
 
-# process-wide traced-verb close hook (ISSUE 18 roofline recording):
-# called as (verb_name, wall_seconds, result) after a SUCCESSFUL traced
-# verb while tracing is enabled. None = no observer = zero extra work.
-_VERB_OBSERVER: Optional[Callable[[str, float, Any], None]] = None
-
-
-def set_verb_observer(fn: Optional[Callable[[str, float, Any], None]]) -> None:
-    """Install (or clear, with None) the traced-verb close observer. One
-    slot per process — a newer install replaces the previous one."""
-    global _VERB_OBSERVER
-    _VERB_OBSERVER = fn
-
-
 def traced_verb(name: str, cat: str = "engine", annotate: bool = True) -> Callable:
     """Decorator instrumenting an engine verb as one span. The disabled
-    path is a single attribute check before delegating. While tracing is
-    on, a successful close additionally feeds the registered verb
-    observer (roofline recording) with the verb's wall time and result —
-    failures are never folded into throughput ceilings."""
+    path is a single attribute check before delegating."""
     import functools
 
     def deco(fn: Callable) -> Callable:
@@ -456,18 +461,8 @@ def traced_verb(name: str, cat: str = "engine", annotate: bool = True) -> Callab
             tr = _TRACER
             if not tr.enabled:
                 return fn(*a, **k)
-            obs = _VERB_OBSERVER
-            if obs is None:
-                with tr.span(name, cat=cat, annotate=annotate):
-                    return fn(*a, **k)
-            t0 = time.perf_counter()
             with tr.span(name, cat=cat, annotate=annotate):
-                out = fn(*a, **k)
-            try:
-                obs(name, time.perf_counter() - t0, out)
-            except Exception:  # recording must never fail the verb
-                pass
-            return out
+                return fn(*a, **k)
 
         return wrapper
 

@@ -25,6 +25,7 @@ both sides' match sets on device.
 
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..obs.tracer import get_tracer
 from ..parallel.mesh import ROW_AXIS, num_row_shards
 from . import collectives
 from .shuffle import _hash_cols
@@ -109,7 +110,7 @@ def _get_compiled_right_prep(mesh: Any, n_keys: int, dtypes: Any, local: bool):
     key = ("rprep", mesh, n_keys, dtypes, local)
     if key not in _JOIN_CACHE:
 
-        def prep(valid: Any, *key_cols: Any):
+        def join_right_prep(valid: Any, *key_cols: Any):
             h, kv = _key_hash_and_valid(jnp, list(key_cols), valid)
             n = h.shape[0]
             inv = jnp.logical_not(kv)
@@ -131,14 +132,14 @@ def _get_compiled_right_prep(mesh: Any, n_keys: int, dtypes: Any, local: bool):
             spec = P(ROW_AXIS)
             _JOIN_CACHE[key] = jax.jit(
                 shard_map(
-                    prep,
+                    join_right_prep,
                     mesh=mesh,
                     in_specs=tuple(spec for _ in range(1 + n_keys)),
                     out_specs=(spec, spec, spec, spec),
                 )
             )
         else:
-            _JOIN_CACHE[key] = jax.jit(prep)
+            _JOIN_CACHE[key] = jax.jit(join_right_prep)
     return _JOIN_CACHE[key]
 
 
@@ -158,7 +159,7 @@ def _get_compiled_probe(
     key = ("probe", mesh, how, n_keys, n_values, dtypes, local, fills)
     if key not in _JOIN_CACHE:
 
-        def probe(*args: Any):
+        def join_probe(*args: Any):
             (f_valid, s_h, order, nv) = args[:4]
             fk = args[4 : 4 + n_keys]
             rk = args[4 + n_keys : 4 + 2 * n_keys]
@@ -186,7 +187,7 @@ def _get_compiled_probe(
                 out_specs=tuple(row for _ in range(n_out)),
             )(f_valid, s_h, order, nv, *fk, *rk, *rv)
 
-        _JOIN_CACHE[key] = jax.jit(probe)
+        _JOIN_CACHE[key] = jax.jit(join_probe)
     return _JOIN_CACHE[key]
 
 
@@ -272,7 +273,9 @@ def device_hash_join(
     kdt = tuple(str(a.dtype) for a in right_keys)
     prep = _get_compiled_right_prep(mesh, n_keys, kdt, local=shuffle)
     s_h, order, nv, dup = prep(right_valid, *right_keys)
-    if bool(np.asarray(jax.device_get(dup)).any()):
+    with get_tracer().span("device.wait", annotate=True, on="join_right_prep"):
+        has_dup = bool(np.asarray(jax.device_get(dup)).any())
+    if has_dup:
         return None  # duplicate keys (or hash collision) → host join
     vdt = tuple(str(a.dtype) for _, a, _ in right_values)
     fills = (
@@ -321,7 +324,7 @@ def _get_compiled_expand_count(mesh: Any, n_keys: int, dtypes: Any, local: bool,
     key = ("xcount", mesh, n_keys, dtypes, local, miss_slot)
     if key not in _JOIN_CACHE:
 
-        def count(f_valid: Any, s_h: Any, nv: Any, *fk: Any):
+        def join_count(f_valid: Any, s_h: Any, nv: Any, *fk: Any):
             fh, fkv = _key_hash_and_valid(jnp, list(fk), f_valid)
             lo = jnp.searchsorted(s_h, fh, side="left")
             hi = jnp.searchsorted(s_h, fh, side="right")
@@ -339,7 +342,7 @@ def _get_compiled_expand_count(mesh: Any, n_keys: int, dtypes: Any, local: bool,
         right = row if local else P()
         _JOIN_CACHE[key] = jax.jit(
             shard_map(
-                count,
+                join_count,
                 mesh=mesh,
                 in_specs=(row, right, right) + tuple(row for _ in range(n_keys)),
                 out_specs=(row, row, row, P()),
@@ -370,7 +373,7 @@ def _get_compiled_expand(
     key = ("xpand", mesh, how, cap, n_keys, n_left, n_values, dtypes, local, fills)
     if key not in _JOIN_CACHE:
 
-        def expand(*args: Any):
+        def join_expand(*args: Any):
             cand, lo, off, f_valid, order = args[:5]
             fk = args[5 : 5 + n_keys]
             lp = args[5 + n_keys : 5 + n_keys + n_left]
@@ -429,7 +432,7 @@ def _get_compiled_expand(
         )
         _JOIN_CACHE[key] = jax.jit(
             shard_map(
-                expand,
+                join_expand,
                 mesh=mesh,
                 in_specs=(row_spec, row_spec, row_spec, row_spec, right)
                 + tuple(row_spec for _ in range(n_keys + n_left))
@@ -484,7 +487,8 @@ def device_expand_join(
         mesh, n_keys, kdt, local=shuffle, miss_slot=(how == "left_outer")
     )
     cand, lo, off, max_total = counter(left_valid, s_h, nv, *fk_arrs)
-    mt = int(np.asarray(jax.device_get(max_total))[0])
+    with get_tracer().span("device.wait", annotate=True, on="join_count"):
+        mt = int(np.asarray(jax.device_get(max_total))[0])
     if mt > MAX_EXPAND_ROWS:
         return None  # output would blow past the per-shard budget → host
     cap = 1 << (max(1, mt) - 1).bit_length()  # pow2 ≥ mt, ≥ 1

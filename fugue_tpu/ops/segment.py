@@ -162,7 +162,7 @@ def _shard_kernel(num_keys: int, agg_specs: Sequence[Tuple[Any, ...]]):
 
     specs, num_vals = _norm_specs(agg_specs)
 
-    def kernel(*args: Any):
+    def segment_partials(*args: Any):
         keys = args[:num_keys]
         values = args[num_keys : num_keys + num_vals]
         valid = args[num_keys + num_vals]
@@ -202,7 +202,7 @@ def _shard_kernel(num_keys: int, agg_specs: Sequence[Tuple[Any, ...]]):
         packed_keys = tuple(k[starts] for k in s_keys)
         return (nseg[None],) + packed_keys + tuple(outs)
 
-    return kernel
+    return segment_partials
 
 
 def _max_of(jnp: Any, dt: Any) -> Any:
@@ -271,7 +271,7 @@ def _get_compiled_mask(mesh: Any):
     cache_key = ("mask", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def mask(template: Any, row_count: Any):
+        def valid_mask(template: Any, row_count: Any):
             def shard_fn(t: Any, rc: Any):
                 n_local = t.shape[0]
                 base = jax.lax.axis_index(ROW_AXIS).astype(jnp.int64) * n_local
@@ -284,7 +284,7 @@ def _get_compiled_mask(mesh: Any):
                 out_specs=P(ROW_AXIS),
             )(template, row_count)
 
-        _COMPILE_CACHE[cache_key] = jax.jit(mask)
+        _COMPILE_CACHE[cache_key] = jax.jit(valid_mask)
     return _COMPILE_CACHE[cache_key]
 
 
@@ -367,7 +367,7 @@ def _get_compiled_minmax(mesh: Any):
     cache_key = ("minmax", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def mm(k: Any, valid: Any):
+        def key_min_max(k: Any, valid: Any):
             def shard_fn(k_: Any, v_: Any):
                 big = jnp.where(v_, k_, jnp.iinfo(k_.dtype).max)
                 small = jnp.where(v_, k_, jnp.iinfo(k_.dtype).min)
@@ -383,7 +383,7 @@ def _get_compiled_minmax(mesh: Any):
                 out_specs=(P(), P()),
             )(k, valid)
 
-        _COMPILE_CACHE[cache_key] = jax.jit(mm)
+        _COMPILE_CACHE[cache_key] = jax.jit(key_min_max)
     return _COMPILE_CACHE[cache_key]
 
 
@@ -408,7 +408,7 @@ def _get_compiled_dense(mesh: Any, buckets: int, agg_sig: Tuple[Tuple[str, str],
     cache_key = ("dense", mesh, buckets, agg_sig, _DENSE_SUM_BACKEND[0])
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(k: Any, kmin: Any, *rest: Any):
+        def dense_segment_agg(k: Any, kmin: Any, *rest: Any):
             values = rest[:num_vals]
             valid = rest[num_vals]
             idx = jnp.where(valid, (k - kmin).astype(jnp.int32), buckets - 1)
@@ -459,7 +459,7 @@ def _get_compiled_dense(mesh: Any, buckets: int, agg_sig: Tuple[Tuple[str, str],
 
         n_out = 1 + len(agg_sig)
         mapped = shard_map(
-            kernel,
+            dense_segment_agg,
             mesh=mesh,
             in_specs=(P(ROW_AXIS), P()) + tuple(P(ROW_AXIS) for _ in range(num_vals + 1)),
             out_specs=tuple(P() for _ in range(n_out)),

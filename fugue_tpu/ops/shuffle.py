@@ -75,13 +75,13 @@ def _get_compiled_dest_hash(mesh: Any, n_keys: int, dtypes: Tuple[Any, ...]):
     cache_key = ("dest_hash", mesh, n_keys, dtypes)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(*cols: Any):
+        def dest_hash(*cols: Any):
             h = _hash_cols(jnp, list(cols))
             return (h % np.uint64(shards)).astype(jnp.int32)
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                dest_hash,
                 mesh=mesh,
                 in_specs=tuple(P(ROW_AXIS) for _ in range(n_keys)),
                 out_specs=P(ROW_AXIS),
@@ -101,7 +101,7 @@ def _get_compiled_dest_even(mesh: Any):
     cache_key = ("dest_even", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(valid: Any):
+        def dest_even(valid: Any):
             local = jnp.cumsum(valid.astype(jnp.int64)) - 1  # local rank
             counts = collectives.all_gather(valid.sum(dtype=jnp.int64), ROW_AXIS)
             me = jax.lax.axis_index(ROW_AXIS)
@@ -116,7 +116,7 @@ def _get_compiled_dest_even(mesh: Any):
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel, mesh=mesh, in_specs=(P(ROW_AXIS),), out_specs=P(ROW_AXIS)
+                dest_even, mesh=mesh, in_specs=(P(ROW_AXIS),), out_specs=P(ROW_AXIS)
             )
         )
     return _COMPILE_CACHE[cache_key]
@@ -131,7 +131,7 @@ def _get_compiled_dest_rand(mesh: Any):
     cache_key = ("dest_rand", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(template: Any, seed: Any):
+        def dest_rand(template: Any, seed: Any):
             me = jax.lax.axis_index(ROW_AXIS)
             key = jax.random.fold_in(jax.random.PRNGKey(seed[0]), me)
             return jax.random.randint(
@@ -140,7 +140,7 @@ def _get_compiled_dest_rand(mesh: Any):
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                dest_rand,
                 mesh=mesh,
                 in_specs=(P(ROW_AXIS), P()),
                 out_specs=P(ROW_AXIS),
@@ -180,7 +180,7 @@ def _get_compiled_counts(mesh: Any):
     cache_key = ("shuffle_counts", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(dest: Any, valid: Any):
+        def shuffle_counts(dest: Any, valid: Any):
             h = (
                 jnp.zeros(shards, dtype=jnp.int32)
                 .at[dest]
@@ -195,7 +195,7 @@ def _get_compiled_counts(mesh: Any):
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                shuffle_counts,
                 mesh=mesh,
                 in_specs=(P(ROW_AXIS), P(ROW_AXIS)),
                 out_specs=(P(), P(), P()),
@@ -223,7 +223,7 @@ def _get_compiled_exchange(
     cache_key = ("exchange", mesh, dtypes, capacity)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(dest: Any, valid: Any, *arrs: Any):
+        def shuffle_exchange(dest: Any, valid: Any, *arrs: Any):
             n = dest.shape[0]
             big_dest = jnp.where(valid, dest, shards)  # invalid rows last
             iota = lax.iota(jnp.int32, n)
@@ -271,7 +271,7 @@ def _get_compiled_exchange(
         n_out = 1 + len(dtypes)
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                shuffle_exchange,
                 mesh=mesh,
                 in_specs=tuple(P(ROW_AXIS) for _ in range(n_in)),
                 out_specs=tuple(P(ROW_AXIS) for _ in range(n_out)),
@@ -292,7 +292,7 @@ def _get_compiled_rank(mesh: Any):
     cache_key = ("shuffle_rank", mesh)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(dest: Any, valid: Any):
+        def shuffle_rank(dest: Any, valid: Any):
             n = dest.shape[0]
             big_dest = jnp.where(valid, dest, shards)
             iota = lax.iota(jnp.int32, n)
@@ -306,7 +306,7 @@ def _get_compiled_rank(mesh: Any):
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                shuffle_rank,
                 mesh=mesh,
                 in_specs=(P(ROW_AXIS), P(ROW_AXIS)),
                 out_specs=P(ROW_AXIS),
@@ -332,7 +332,7 @@ def _get_compiled_round(
     cache_key = ("xround", mesh, dtypes, cap, out_cap)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(dest: Any, valid: Any, rank: Any, out_len: Any, r: Any, *rest: Any):
+        def exchange_round(dest: Any, valid: Any, rank: Any, out_len: Any, r: Any, *rest: Any):
             arrs = rest[: len(dtypes)]
             bufs = rest[len(dtypes) :]
             lo = r[0] * cap
@@ -374,7 +374,7 @@ def _get_compiled_round(
         row = P(ROW_AXIS)
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                exchange_round,
                 mesh=mesh,
                 in_specs=(row, row, row, row, P())
                 + tuple(row for _ in range(2 * len(dtypes))),
@@ -454,12 +454,12 @@ def _get_compiled_lenmask(mesh: Any, out_cap: int):
     cache_key = ("lenmask", mesh, out_cap)
     if cache_key not in _COMPILE_CACHE:
 
-        def kernel(out_len: Any):
+        def len_mask(out_len: Any):
             return lax.iota(jnp.int32, out_cap) < out_len[0]
 
         _COMPILE_CACHE[cache_key] = jax.jit(
             shard_map(
-                kernel,
+                len_mask,
                 mesh=mesh,
                 in_specs=(P(ROW_AXIS),),
                 out_specs=P(ROW_AXIS),

@@ -35,6 +35,7 @@ from ..column.functions import is_agg
 from ..dataframe import ArrayDataFrame, DataFrame, PandasDataFrame
 from ..exceptions import FugueSQLRuntimeError, FugueSQLSyntaxError
 from ..execution.execution_engine import ExecutionEngine
+from ..obs import NULL_SPAN, get_tracer
 from .parser import (
     JoinNode,
     LimitNode,
@@ -215,11 +216,16 @@ class SQLExecutor:
                     f"{local.schema.names} (aggregated selects can only order "
                     "by projected columns)"
                 )
-            pdf = local.as_pandas().sort_values(
-                sort_names,
-                ascending=[a for _, a in node.by],
-                na_position="first",
-            )
+            with get_tracer().span(
+                "host.op", annotate=True, op="sort", reason="order_by"
+            ) as sp:
+                if sp is not NULL_SPAN:
+                    sp.set(rows_in=local.count())
+                pdf = local.as_pandas().sort_values(
+                    sort_names,
+                    ascending=[a for _, a in node.by],
+                    na_position="first",
+                )
             if len(extras) > 0:
                 pdf = pdf.drop(columns=extras)
             schema = local.schema - extras if len(extras) > 0 else local.schema

@@ -925,13 +925,21 @@ def fugue_sql(
     """Run FugueSQL and return the LAST statement's dataframe
     (reference ``fugue/sql/api.py:18``)."""
     from ..dataframe.api import get_native_as_df
+    from ..obs import NULL_SPAN, current_trace_id, get_tracer, trace_scope
 
-    dag = fugue_sql_flow(query, *args, **kwargs)
-    last = dag._last_compiled
-    if last is None:
-        raise FugueSQLSyntaxError("fugue_sql requires the last statement to output a dataframe")
-    last.yield_dataframe_as("__fugue_sql_result__", as_local=as_local)
-    dag.run(engine, engine_conf)
+    # while tracing, the compile and the run share one trace id
+    scope = (
+        trace_scope()
+        if get_tracer().enabled and current_trace_id() is None
+        else NULL_SPAN
+    )
+    with scope:
+        dag = fugue_sql_flow(query, *args, **kwargs)
+        last = dag._last_compiled
+        if last is None:
+            raise FugueSQLSyntaxError("fugue_sql requires the last statement to output a dataframe")
+        last.yield_dataframe_as("__fugue_sql_result__", as_local=as_local)
+        dag.run(engine, engine_conf)
     result = dag.yields["__fugue_sql_result__"].result
     dag.release_task_results()  # free intermediates now, not at cyclic GC
     return result if as_fugue else get_native_as_df(result)
@@ -953,6 +961,9 @@ def fugue_sql_flow(query: str, *args: Any, **kwargs: Any) -> "FugueSQLWorkflow":
         global_vars,
         local_vars,
     )
-    compiler.compile(code)
+    from ..obs import get_tracer
+
+    with get_tracer().span("sql.parse", annotate=True, chars=len(code)):
+        compiler.compile(code)
     dag._last_compiled = compiler.last  # type: ignore
     return dag

@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional
 from ..collections.sql import StructuredRawSQL
 from ..dataframe import DataFrame, DataFrames
 from ..execution.execution_engine import SQLEngine
+from ..obs import get_tracer
 from .executor import SQLExecutor
 from .parser import SQLParser
 
@@ -34,7 +35,8 @@ class LocalSQLEngine(SQLEngine):
 
     def select(self, dfs: DataFrames, statement: StructuredRawSQL) -> DataFrame:
         sql = statement.construct(dialect=self.dialect, log=self.log)
-        plan = SQLParser(sql).parse_full()
+        with get_tracer().span("sql.parse", annotate=True, chars=len(sql)):
+            plan = SQLParser(sql).parse_full()
         return SQLExecutor(self.execution_engine, dict(dfs)).run(plan)
 
     # -- table storage ------------------------------------------------------

@@ -6,7 +6,6 @@ so a warm server converges across submissions and survives restart.
 ``fugue.tpu.tuning.enabled=false`` restores the static-conf engine
 bit-identically."""
 
-from .roofline import RooflineRecorder, install_verb_observer, rooflines_enabled
 from .stats import TuningStats
 from .store import TunedStore, default_tuned_path, resolve_tuned_path
 from .tuner import (
@@ -24,7 +23,6 @@ from .tuner import (
 
 __all__ = [
     "ExchangeHandle",
-    "RooflineRecorder",
     "StreamHandle",
     "TunedStore",
     "Tuner",
@@ -34,10 +32,8 @@ __all__ = [
     "current_scope",
     "default_tuned_path",
     "describe_tuning",
-    "install_verb_observer",
     "plan_fingerprint",
     "resolve_tuned_path",
-    "rooflines_enabled",
     "run_scope",
     "tuning_enabled",
 ]

@@ -1,9 +1,9 @@
 """Persistence for learned settings — the ``_tuned.json`` plan ledger.
 
 The file is the SAME ``ops/_tuned.json`` the dense-sum kernel A/B has
-always used; this module owns its ``"tuning"`` and ``"rooflines"``
-top-level keys and preserves every other key verbatim on publish, so the
-tenants of the file never clobber each other. Layout::
+always used; this module owns its ``"tuning"`` top-level key and
+preserves every other key verbatim on publish, so the tenants of the
+file never clobber each other. Layout::
 
     {
       "dense_sum": {...},            # ops/segment.py's A/B winner
@@ -18,15 +18,6 @@ tenants of the file never clobber each other. Layout::
             "joins":   {"<sid>": {"left_bytes", "right_bytes",
                                    "right_rows", "buckets", "obs",
                                    "converged", "evidence"}}
-          }
-        }
-      },
-      "rooflines": {                 # ISSUE 18 record-only throughput folds
-        "version": 1,
-        "entries": {
-          "<verb>|<dtype-class>|w<width>": {
-            "ts", "obs", "rows", "bytes", "wall_s",
-            "best_bytes_s", "best_rows_s", "last_bytes_s", "last_rows_s"
           }
         }
       }
@@ -116,9 +107,6 @@ class TunedStore:
         self._mem: Dict[str, Dict[str, Any]] = {}
         self._cache: Dict[str, Dict[str, Any]] = {}
         self._cache_sig: Any = ("", -1)
-        # ditto for the "rooflines" top-level key (ISSUE 18 record-only
-        # per-verb throughput ceilings — docs/tuning.md)
-        self._mem_roof: Dict[str, Dict[str, Any]] = {}
 
     def _inc(self, name: str, n: int = 1) -> None:
         if self._stats is not None:
@@ -156,58 +144,6 @@ class TunedStore:
             return {}
         # tolerate foreign/garbage entries: only dict-valued plans survive
         return {str(k): v for k, v in plans.items() if isinstance(v, dict)}
-
-    def _roof_of(self, doc: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
-        roof = doc.get("rooflines")
-        if not isinstance(roof, dict):
-            return {}
-        entries = roof.get("entries")
-        if not isinstance(entries, dict):
-            return {}
-        return {str(k): v for k, v in entries.items() if isinstance(v, dict)}
-
-    @staticmethod
-    def _merge_roof_entry(
-        a: Dict[str, Any], b: Dict[str, Any]
-    ) -> Dict[str, Any]:
-        """Reconcile two VIEWS of one cumulative fold entry (the file's
-        and this process's memory). Each view's totals (obs/rows/bytes/
-        wall_s) and best_* rates only ever grow, so element-wise max never
-        double-counts — and when one view is a superset of the other (the
-        common case: our publish landed, then another process folded on
-        top), max recovers exactly the fresher superset. ``last_*``/``ts``
-        travel as a block from whichever view folded more recently."""
-        out = dict(a)
-        for k, v in b.items():
-            if k == "ts" or k.startswith("last_"):
-                continue
-            cur = out.get(k)
-            if isinstance(v, (int, float)) and isinstance(cur, (int, float)):
-                out[k] = max(cur, v)
-            elif cur is None:
-                out[k] = v
-        src = b if float(b.get("ts", 0) or 0) >= float(a.get("ts", 0) or 0) else a
-        for k, v in src.items():
-            if k == "ts" or k.startswith("last_"):
-                out[k] = v
-        return out
-
-    def _overlay_roof_locked(
-        self, entries: Dict[str, Dict[str, Any]]
-    ) -> Dict[str, Dict[str, Any]]:
-        for k, v in self._mem_roof.items():
-            cur = entries.get(k)
-            entries[k] = (
-                dict(v) if cur is None else self._merge_roof_entry(cur, v)
-            )
-        return entries
-
-    def rooflines(self) -> Dict[str, Dict[str, Any]]:
-        """All roofline entries (``<verb>|<dtype-class>|w<width>`` →
-        throughput fold), the file's view reconciled with this process's
-        memory (:meth:`_merge_roof_entry`)."""
-        with self._lock:
-            return self._overlay_roof_locked(self._roof_of(self._read_file()))
 
     def plans(self) -> Dict[str, Dict[str, Any]]:
         """All plan entries, file overlaid with this process's memory
@@ -302,29 +238,3 @@ class TunedStore:
             # unwritable store: memory-only from here on, one warning
             _warn_once(self.path, "unwritable", str(ex))
             return False
-
-    def publish_rooflines(
-        self, mutate: Callable[[Dict[str, Any]], Optional[Dict[str, Any]]]
-    ) -> bool:
-        """Apply ``mutate(entries) -> entries | None`` to the
-        ``"rooflines"`` top-level key and persist — the same
-        read-merge-write + atomic-replace + LRU discipline as
-        :meth:`publish`, preserving every other key (``tuning``,
-        ``dense_sum``) verbatim. ``None`` = nothing to record."""
-        with self._lock:
-            doc = self._read_file()
-            entries = self._overlay_roof_locked(self._roof_of(doc))
-            out = mutate(dict(entries))
-            if out is None:
-                return False
-            # stale-entry eviction: LRU by last-fold timestamp, the same
-            # bound as plan entries (the two tables share max_entries)
-            while len(out) > self.max_entries:
-                victim = min(out, key=lambda k: float(out[k].get("ts", 0) or 0))
-                out.pop(victim)
-                self._inc("evictions")
-            self._mem_roof = {k: dict(v) for k, v in out.items()}
-            doc["rooflines"] = {"version": 1, "entries": out}
-            if self._write_doc_locked(doc):
-                self._inc("roofline_publishes")
-            return True

@@ -588,17 +588,11 @@ class Tuner:
         self.store = TunedStore(
             resolve_tuned_path(conf), max_entries=max_entries, stats=self.stats
         )
-        # per-verb roofline folds (ISSUE 18, record-only) — published into
-        # the same store under its "rooflines" key at flush
-        from .roofline import RooflineRecorder
-
-        self.roofline = RooflineRecorder(self.store, stats=self.stats)
 
     # MetricsRegistry source contract (fugue_tpu/obs/registry.py)
     def as_dict(self) -> Dict[str, Any]:
         out = self.stats.as_dict()
         out["entries"] = self.store.count()
-        out["roofline_pending"] = self.roofline.pending_count()
         return out
 
     def reset(self) -> None:
@@ -732,9 +726,6 @@ class Tuner:
         changed setting, a convergence flip, a >20% cardinality drift);
         bookkeeping-only updates stay in memory — a converged warm server
         does not rewrite the file on every submission."""
-        # drain the run's roofline folds first — they publish (or no-op)
-        # independently of whether any knob observation landed below
-        self.roofline.flush()
         with scope._lock:
             stream_obs = list(scope.stream_obs)
             exchanges = list(scope.exchanges)

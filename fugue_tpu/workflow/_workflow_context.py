@@ -253,6 +253,7 @@ class FugueWorkflowContext:
         with get_tracer().span(
             "workflow.task",
             cat="workflow",
+            annotate=True,
             parent=getattr(self, "_trace_root", None),
             task=task.name or type(task).__name__,
         ) as sp:

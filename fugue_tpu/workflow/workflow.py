@@ -910,7 +910,9 @@ class FugueWorkflow:
         from ..plan import optimize_tasks
 
         tracer = get_tracer()
-        with tracer.span("plan.optimize", cat="plan", tasks=len(self._tasks)) as psp:
+        with tracer.span(
+            "plan.optimize", cat="plan", annotate=True, tasks=len(self._tasks)
+        ) as psp:
             run_tasks, aliases, removed, report = optimize_tasks(
                 self._tasks,
                 plan_conf,
@@ -964,7 +966,11 @@ class FugueWorkflow:
         try:
             with e.run_conf_scope(self._conf), e._as_borrowed_context():
                 with trace_ctx, run_ctx, tracer.span(
-                    "workflow.run", cat="workflow", tasks=len(run_tasks), **run_attrs
+                    "workflow.run",
+                    cat="workflow",
+                    annotate=True,
+                    tasks=len(run_tasks),
+                    **run_attrs,
                 ), _tuning_scope(e, self._last_plan_fingerprint, plan_conf):
                     ctx.run(
                         run_tasks,

@@ -427,6 +427,7 @@ def test_engine_stats_surface_and_shims():
             "cache",
             "tuning",
             "shuffle",
+            "host_ops",
             "latency",
             "telemetry",
         }
