@@ -857,6 +857,11 @@ class JaxDataFrame(DataFrame):
                 inner: DataFrame = self._pending_src[schema.names]
             else:
                 inner = ArrowDataFrame(self._pending_table().select(schema.names))
+        return self._lazy_over(inner)
+
+    def _lazy_over(self, inner: DataFrame) -> "JaxDataFrame":
+        """A not-yet-ingested frame over ``inner`` with this frame's mesh
+        and ingest settings."""
         return JaxDataFrame(
             inner,
             mesh=self._mesh,
@@ -888,8 +893,18 @@ class JaxDataFrame(DataFrame):
         return self._with(schema, dc, ht)
 
     def rename(self, columns: Dict[str, str]) -> DataFrame:
-        self._ensure_device()
         schema = self.schema.rename(columns)  # validates
+        pending = self._pending_snapshot()
+        if pending is not None:
+            # not yet ingested: rename the pending table and stay lazy
+            return self._lazy_over(
+                ArrowDataFrame(
+                    pending.rename_columns(
+                        [columns.get(n, n) for n in pending.column_names]
+                    )
+                )
+            )
+        self._ensure_device()
         dc = {columns.get(k, k): v for k, v in self._device_cols.items()}
         ht = (
             self._host_tbl.rename_columns(

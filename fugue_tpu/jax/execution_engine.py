@@ -3055,8 +3055,10 @@ class JaxExecutionEngine(ExecutionEngine):
             elif enc["kind"] == "datetime":
                 ints = res[c]
                 na = ints.isna()
+                # the epoch's storage width: arrow casts date32 from int32 only
+                storage = np.int32 if pa.types.is_date32(enc["type"]) else np.int64
                 arr = pa.array(
-                    ints.fillna(0).to_numpy().astype(np.int64),
+                    ints.fillna(0).to_numpy().astype(storage),
                     mask=na.to_numpy() if na.any() else None,
                 ).cast(enc["type"])
                 res[c] = arr.to_pandas()
@@ -3527,23 +3529,31 @@ class JaxExecutionEngine(ExecutionEngine):
         agg_refused: Optional[str] = None
         if where is None and sc.has_agg and not sc.is_distinct:
             from ..collections.partition import PartitionSpec as _PSpec
-            from ..column.expressions import _NamedColumnExpr as _Named
             from ..column.functions import is_agg as _is_agg
 
             keys = [c for c in sc.all_cols if not _is_agg(c)]
             aggs = [c for c in sc.all_cols if _is_agg(c)]
-            agg_refused = "no_keys"
-            if len(keys) > 0 and not all(
-                isinstance(k, _Named) and k.as_type is None and k.as_name == ""
-                for k in keys
-            ):
-                agg_refused = "computed_keys"
-            elif len(keys) > 0:
-                spec = _PSpec(by=[k.name for k in keys])
-                agg_plan = _device_agg_plan(jdf, spec.partition_by, aggs)
+            pre = _agg_pre_projection(jdf, keys, aggs)
+            if isinstance(pre, str):
+                agg_refused = pre
+            else:
+                work = jdf
+                if len(pre["helpers"]) > 0:
+                    # computed keys and expression arguments become helper
+                    # columns of one device projection
+                    with get_tracer().span(
+                        "agg.project", annotate=True,
+                        args=pre["made_args"], keys=pre["made_keys"],
+                    ):
+                        work = self._device_project(
+                            jdf, SelectColumns(*pre["passthrough"], *pre["helpers"])
+                        )
+                agg_plan = _device_agg_plan(work, pre["by"], pre["aggs"])
                 agg_refused = agg_plan if isinstance(agg_plan, str) else None
                 if agg_refused is None:
-                    res = self.aggregate(jdf, spec, aggs)
+                    res = self.aggregate(work, _PSpec(by=pre["by"]), pre["aggs"])
+                    if len(pre["renames"]) > 0:
+                        res = res.rename(pre["renames"])
                     if having is not None:
                         # the aggregate result is O(groups): host filter;
                         # aggregate subexpressions read their computed
@@ -3608,7 +3618,7 @@ class JaxExecutionEngine(ExecutionEngine):
 
         if len(computed) > 0:
 
-            def compute(cols: Dict[str, Any]) -> Dict[str, Any]:
+            def project_columns(cols: Dict[str, Any]) -> Dict[str, Any]:
                 import jax.numpy as jnp
 
                 out = {}
@@ -3622,7 +3632,7 @@ class JaxExecutionEngine(ExecutionEngine):
 
             cache_key = ("project", tuple(c.__uuid__() for c in computed), jdf.mesh)
             if cache_key not in self._jit_cache:
-                self._jit_cache[cache_key] = jax.jit(compute)
+                self._jit_cache[cache_key] = jax.jit(project_columns)
             out_cols.update(self._jit_cache[cache_key](dict(jdf.device_cols)))
         out_cols = {c.output_name: out_cols[c.output_name] for c in exprs}
         if schema is None:
@@ -3630,7 +3640,7 @@ class JaxExecutionEngine(ExecutionEngine):
             for c in exprs:
                 t = c.infer_type(jdf.schema)
                 fields.append(
-                    pa.field(c.output_name, t if t is not None else pa.from_numpy_dtype(np.asarray(out_cols[c.output_name]).dtype))
+                    pa.field(c.output_name, t if t is not None else pa.from_numpy_dtype(np.dtype(out_cols[c.output_name].dtype)))
                 )
             schema = Schema(fields)
         # pass-through named columns keep their NaN-free proof; computed
@@ -3948,6 +3958,13 @@ class JaxExecutionEngine(ExecutionEngine):
                     )
                 arr = self._jit_cache[cache_key](arr, jdf.null_masks[src])
             value_arrs[src] = arr
+        # keys of known range pack into one dense group index: dictionary
+        # codes (-1 is NULL) and null masks
+        key_ranges = {mn: (0, 1) for mn in mask_names.values()}
+        for k in keys:
+            enc = jdf.encodings.get(k)
+            if enc is not None and enc["kind"] == "dict":
+                key_ranges[k] = (-1, len(enc["dictionary"]) - 1)
         # single plain-int key: reuse the frame's cached range probe so
         # repeated aggregates don't re-pay the device→host roundtrip
         range_hint = None
@@ -3960,6 +3977,7 @@ class JaxExecutionEngine(ExecutionEngine):
             )
         ):
             range_hint = jdf.key_range(keys[0])
+            key_ranges[keys[0]] = range_hint
         agg_entries = [
             (
                 name,
@@ -3989,7 +4007,7 @@ class JaxExecutionEngine(ExecutionEngine):
             key_cols,
             agg_entries,
             jdf.device_valid_mask(),
-            range_hint=range_hint,
+            key_ranges=key_ranges,
         )
         merged = merge_partials(
             partials,
@@ -4205,6 +4223,115 @@ def _host_bytes(df: DataFrame) -> int:
     if isinstance(native, pd.DataFrame):
         return int(native.memory_usage(index=False).sum())
     return 0
+
+
+def _agg_pre_projection(
+    jdf: JaxDataFrame, keys: List[ColumnExpr], aggs: List[ColumnExpr]
+) -> Union[dict, str]:
+    """A grouped select in the device aggregate's terms, where every group
+    key and every aggregate argument is a column of one frame.
+
+    A renamed key groups by its source column and takes its name back
+    after the aggregate (``renames``). Any other computed key, and every
+    argument that is not a bare column, becomes a helper column that one
+    device projection computes beside the source columns it passes through;
+    arguments of the same structure share one helper. An integer summed or
+    averaged is widened to int64 first, as the host sums it. Returns the
+    ``host.op`` reason instead when a key or an argument cannot be
+    evaluated over the frame's plain device columns."""
+    from ..column.expressions import (
+        _FuncExpr,
+        _LitColumnExpr,
+        _NamedColumnExpr,
+        col,
+        structural_key,
+    )
+
+    if len(keys) == 0:
+        return "no_keys"
+    plain = {
+        k: v
+        for k, v in jdf.device_cols.items()
+        if k not in jdf.encodings and k not in jdf.null_masks
+    }
+    agg_names = {c.output_name for c in aggs}
+    prefix = _safe_prefix(
+        "__agg_", jdf.schema.names, agg_names, [k.output_name for k in keys]
+    )
+    sources: Dict[str, None] = {}  # passed through, in first-use order
+    helpers: Dict[str, ColumnExpr] = {}  # structural key -> aliased expr
+    by: List[str] = []
+    renames: Dict[str, str] = {}
+    made_keys: List[str] = []
+    made_args: List[str] = []
+    for k in keys:
+        name = k.output_name
+        if isinstance(k, _NamedColumnExpr) and k.as_type is None and not k.wildcard:
+            if k.name not in jdf.device_cols:
+                return "key_not_on_device"
+            if k.name not in by and k.name not in agg_names:
+                by.append(k.name)
+                sources[k.name] = None
+                if name != k.name:
+                    renames[k.name] = name
+                continue
+        elif (
+            isinstance(k, _LitColumnExpr)
+            or name == ""
+            or not can_evaluate_on_device(k, plain)
+        ):
+            return "computed_keys"
+        # a computed key, or a source already grouped by under another name
+        h = f"{prefix}key{len(made_keys)}"
+        helpers[h] = k.alias(h)
+        made_keys.append(h)
+        by.append(h)
+        renames[h] = name
+    dev_aggs: List[ColumnExpr] = []
+    for c in aggs:
+        arg = c.args[0] if isinstance(c, _FuncExpr) and len(c.args) == 1 else None
+        if (
+            arg is None
+            or (isinstance(arg, _NamedColumnExpr) and arg.as_type is None)
+            or (
+                c.func.upper() == "COUNT"
+                and isinstance(arg, _LitColumnExpr)
+                and arg.value is not None
+            )
+        ):
+            # bare columns, COUNT(*) and COUNT(1): the planner's own terms
+            if isinstance(arg, _NamedColumnExpr) and not arg.wildcard:
+                if arg.name not in jdf.device_cols:
+                    return "value_not_on_device"
+                sources[arg.name] = None
+            dev_aggs.append(c)
+            continue
+        tp = arg.infer_type(jdf.schema)
+        if (
+            tp is None
+            or not (pa.types.is_integer(tp) or pa.types.is_floating(tp))
+            or not can_evaluate_on_device(arg, plain)
+        ):
+            return "agg_of_expression"
+        if c.func.upper() in ("SUM", "AVG") and pa.types.is_integer(tp):
+            arg = arg.cast(pa.int64())
+        key = structural_key(arg)
+        if key not in helpers:
+            h = f"{prefix}arg{len(made_args)}"
+            helpers[key] = arg.alias(h)
+            made_args.append(h)
+        dev = c._copy()
+        dev._args = [col(helpers[key].output_name)]
+        dev_aggs.append(dev)
+    return {
+        "passthrough": [col(s) for s in sources],
+        "helpers": list(helpers.values()),
+        "by": by,
+        "renames": renames,
+        "aggs": dev_aggs,
+        "made_keys": made_keys,
+        "made_args": made_args,
+    }
 
 
 def _device_agg_plan(

@@ -13,12 +13,19 @@ The TPU-native replacement for the reference's backend-SQL groupby
    per shard cross the wire (bounded transfer); partials merge by
    re-aggregation on host.
 
+Keys whose ranges multiply to a small table (integer keys, dictionary codes
+and null masks, several packed into one mixed-radix index) take a dense
+bucket table instead of the sort. On a TPU, up to ``MAX_MASKED_BUCKETS``
+buckets, the table is filled by one masked reduction a bucket rather than a
+scatter: there each row of a scatter costs far more than a masked pass.
+
 Compiled executables are cached per (mesh, key-count, agg signature) — jit
 re-tracing happens only on dtype/shape changes.
 
 Supported aggregations: sum, count, min, max (avg = sum+count at merge).
 """
 
+import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -291,6 +298,16 @@ def _get_compiled_mask(mesh: Any):
 # max bucket table size for the dense (sort-free) groupby path
 _DENSE_MAX_RANGE = 1 << 18
 
+# the most buckets the dense kernel fills by masked reductions (one a bucket
+# and a value, _MASKED_STEP buckets unrolled a step of a loop) instead of a
+# scatter-add. On one v5e at 6M rows and eleven outputs over six float64
+# values, the masked table took 12.7 ms at 16 buckets and 4.32 s at 8192,
+# about 0.53 ms a bucket; the scatter took 4.47 s at 256 and 5.72 s at 8192.
+# On a CPU a scatter-add is cheap, so there the masked reductions take one
+# step's worth of buckets only.
+MAX_MASKED_BUCKETS = 8192
+_MASKED_STEP = 16
+
 # float32 SUM engine inside the dense kernel: "scatter" (XLA scatter-add),
 # "onehot" (chunked one-hot MXU matmul, jnp), or "pallas" (the Pallas TPU
 # kernel in ops/pallas_groupby.py, at most its MAX_BUCKETS buckets; it
@@ -388,12 +405,15 @@ def _get_compiled_minmax(mesh: Any):
 
 
 def _get_compiled_dense(mesh: Any, buckets: int, agg_sig: Tuple[Tuple[str, str], ...]):
-    """Sort-free per-shard groupby: scatter-add into a dense bucket table,
-    merged ACROSS shards on device (psum/pmin/pmax over the rows axis).
+    """Sort-free per-shard groupby into a dense bucket table, merged ACROSS
+    shards on device (psum/pmin/pmax over the rows axis).
 
     Applies when the key range fits ``buckets`` — the common case — and
-    avoids ``lax.sort`` entirely (sorts are the slow path on TPU; scatter
-    reductions vectorize on the VPU). The cross-shard merge rides ICI and
+    avoids ``lax.sort`` entirely (sorts are the slow path on TPU). Up to
+    ``MAX_MASKED_BUCKETS`` buckets on a TPU (``_MASKED_STEP`` elsewhere)
+    each bucket is one masked reduction
+    (a tree reduction, so float64 sums keep their digits); above, rows
+    scatter-add into the table. The cross-shard merge rides ICI and
     leaves ONE replicated table, so the host transfer is O(buckets), not
     O(shards × buckets).
     """
@@ -407,47 +427,79 @@ def _get_compiled_dense(mesh: Any, buckets: int, agg_sig: Tuple[Tuple[str, str],
     agg_sig, num_vals = _norm_specs(agg_sig)
     cache_key = ("dense", mesh, buckets, agg_sig, _DENSE_SUM_BACKEND[0])
     if cache_key not in _COMPILE_CACHE:
+        on_tpu = np.asarray(mesh.devices).flat[0].platform == "tpu"
+        masked_max = MAX_MASKED_BUCKETS if on_tpu else _MASKED_STEP
 
         def dense_segment_agg(k: Any, kmin: Any, *rest: Any):
             values = rest[:num_vals]
             valid = rest[num_vals]
             idx = jnp.where(valid, (k - kmin).astype(jnp.int32), buckets - 1)
-            present = collectives.psum(
-                jnp.zeros(buckets, dtype=jnp.int64).at[idx].add(
+            if buckets <= masked_max:
+                step = min(buckets, _MASKED_STEP)
+
+                def masked(reduce: Any, fill: Any) -> Any:
+                    def table(a: Any) -> Any:
+                        f = jnp.asarray(fill(jnp, a.dtype), a.dtype)
+
+                        def one_step(c: Any) -> Any:
+                            return jnp.stack(
+                                [
+                                    reduce(jnp.where(valid & (idx == c * step + j), a, f))
+                                    for j in range(step)
+                                ]
+                            )
+
+                        steps = jnp.arange(buckets // step, dtype=jnp.int32)
+                        return lax.map(one_step, steps).reshape(buckets).astype(a.dtype)
+
+                    return table
+
+                sum_of = masked(jnp.sum, lambda jnp_, dt: 0)
+                min_of = masked(jnp.min, _max_of)
+                max_of = masked(jnp.max, _min_of)
+                counts = sum_of(valid.astype(jnp.int64))
+            else:
+                counts = jnp.zeros(buckets, dtype=jnp.int64).at[idx].add(
                     valid.astype(jnp.int64)
-                ),
-                ROW_AXIS,
-            )
-            def sum_of(a: Any) -> Any:
-                if (
-                    _DENSE_SUM_BACKEND[0] != "scatter"
-                    and a.dtype == jnp.float32
-                ):
-                    # one-hot MXU matmul path (ops/pallas_groupby.py):
-                    # scatter on TPU serializes; histograms ride the MXU.
-                    # float32 only — the MXU has no 64-bit path, so f64
-                    # exactness keeps the scatter/XLA-emulation route
-                    from .pallas_groupby import bin_sum_idx
+                )
 
-                    return bin_sum_idx(idx, a, buckets, _DENSE_SUM_BACKEND[0])
-                return jnp.zeros(buckets, dtype=a.dtype).at[idx].add(a)
+                def sum_of(a: Any) -> Any:
+                    if (
+                        _DENSE_SUM_BACKEND[0] != "scatter"
+                        and a.dtype == jnp.float32
+                    ):
+                        # one-hot MXU matmul path (ops/pallas_groupby.py):
+                        # scatter on TPU serializes; histograms ride the MXU.
+                        # float32 only — the MXU has no 64-bit path, so f64
+                        # exactness keeps the scatter/XLA-emulation route
+                        from .pallas_groupby import bin_sum_idx
 
+                        return bin_sum_idx(idx, a, buckets, _DENSE_SUM_BACKEND[0])
+                    return jnp.zeros(buckets, dtype=a.dtype).at[idx].add(a)
+
+                def min_of(a: Any) -> Any:
+                    return (
+                        jnp.full(buckets, _max_of(jnp, a.dtype), dtype=a.dtype)
+                        .at[idx]
+                        .min(a)
+                    )
+
+                def max_of(a: Any) -> Any:
+                    return (
+                        jnp.full(buckets, _min_of(jnp, a.dtype), dtype=a.dtype)
+                        .at[idx]
+                        .max(a)
+                    )
+
+            present = collectives.psum(counts, ROW_AXIS)
             outs = _agg_outputs(
                 jnp,
                 agg_sig,
                 values,
                 valid,
                 sum_of=sum_of,
-                min_of=lambda a: (
-                    jnp.full(buckets, _max_of(jnp, a.dtype), dtype=a.dtype)
-                    .at[idx]
-                    .min(a)
-                ),
-                max_of=lambda a: (
-                    jnp.full(buckets, _min_of(jnp, a.dtype), dtype=a.dtype)
-                    .at[idx]
-                    .max(a)
-                ),
+                min_of=min_of,
+                max_of=max_of,
                 count_all=present,
                 merge_ops={
                     "sum": lambda t: collectives.psum(t, ROW_AXIS),
@@ -530,19 +582,50 @@ def device_dense_groupby(
     return outs[0], [(spec[0], arr) for spec, arr in zip(agg_sig, outs[1:])]
 
 
+def _get_compiled_pack(num_keys: int):
+    """One mixed-radix index over ``num_keys`` keys; the keys' minimums and
+    radices are arguments, so one program serves every range."""
+    import jax
+    import jax.numpy as jnp
+
+    cache_key = ("pack", num_keys)
+    if cache_key not in _COMPILE_CACHE:
+
+        def pack_keys(lo: Any, radix: Any, *keys: Any):
+            g = jnp.zeros(keys[0].shape, jnp.int32)
+            for i, k in enumerate(keys):
+                g = g * radix[i] + (k.astype(jnp.int64) - lo[i]).astype(jnp.int32)
+            return g
+
+        _COMPILE_CACHE[cache_key] = jax.jit(pack_keys)
+    return _COMPILE_CACHE[cache_key]
+
+
 def _dense_groupby_partials(
     mesh: Any,
-    key_name: str,
-    key_arr: Any,
+    key_cols: Dict[str, Any],
+    ranges: List[Tuple[int, int]],
     agg_cols: List[Tuple[Any, ...]],
     valid: Any,
-    kmin: int,
-    buckets: int,
 ) -> "Any":
+    """The dense groupby of keys whose ``(min, max)`` are ``ranges``:
+    several keys are packed into one mixed-radix index first, and the
+    present buckets unpack to one host row each."""
     import jax
     import numpy as np_
     import pandas as pd
 
+    radices = [hi - lo + 1 for lo, hi in ranges]
+    buckets = dense_buckets(math.prod(radices))
+    if len(key_cols) == 1:
+        key_arr, kmin = next(iter(key_cols.values())), ranges[0][0]
+    else:
+        key_arr = _get_compiled_pack(len(ranges))(
+            np_.array([lo for lo, _ in ranges], np_.int64),
+            np_.array(radices, np_.int32),
+            *key_cols.values(),
+        )
+        kmin = 0
     present_a, named = device_dense_groupby(
         mesh, key_arr, agg_cols, valid, kmin, buckets
     )
@@ -557,7 +640,12 @@ def _dense_groupby_partials(
     # the overflow bucket (buckets-1) may mix padding rows; presence counts
     # only valid rows, so zero-presence buckets drop out naturally
     (idx,) = np_.nonzero(present > 0)
-    data: Dict[str, Any] = {key_name: idx.astype(np_.int64) + kmin}
+    keys: Dict[str, Any] = {}
+    rest = idx.astype(np_.int64)
+    for name, (lo, _), r in reversed(list(zip(key_cols, ranges, radices))):
+        keys[name] = rest % r + lo
+        rest = rest // r
+    data: Dict[str, Any] = {name: keys[name] for name in key_cols}
     for spec, arr in zip(agg_sig, host[1:]):
         data[spec[0]] = arr[idx]
     return pd.DataFrame(data)
@@ -574,19 +662,20 @@ def device_groupby_partials(
     agg_cols: List[Tuple[Any, ...]],
     valid_mask: Any,
     max_partial_rows: Optional[int] = None,
-    range_hint: Optional[Tuple[int, int]] = None,
+    key_ranges: Optional[Dict[str, Tuple[int, int]]] = None,
 ) -> "Any":
     """Run the device phase; return a host pandas frame of per-shard-group
-    partials. Strategy: single int key with a small range → dense scatter-add
-    (no sort); otherwise lexicographic sort + segment reduction. Only
-    ``O(shards * groups)`` rows are transferred either way.
+    partials. Strategy: keys whose ranges multiply to a small group index
+    → dense bucket table (no sort); otherwise lexicographic sort + segment
+    reduction. Only ``O(shards * groups)`` rows are transferred either way.
 
     ``agg_cols`` entries are ``(name, agg, arr)`` or ``(name, agg, arr,
     nullable)`` — ``nullable=False`` marks a float column proved NaN-free,
     which skips the NaN-as-NULL masking work in the kernels.
-    ``range_hint`` is the caller's cached (min, max) of the single key
-    column (``JaxDataFrame.key_range``) — it skips the device probe AND its
-    device→host roundtrip.
+    ``key_ranges`` holds the (min, max) the caller knows of some keys:
+    dictionary codes, null masks, a frame's cached ``key_range``. When every
+    other key is an integer, each is probed on the device (one roundtrip
+    a key).
     """
     import jax
     import numpy as np_
@@ -596,25 +685,22 @@ def device_groupby_partials(
 
     key_names = list(key_cols.keys())
     valid0 = valid_mask
-    if len(key_names) == 1:
-        import jax.numpy as jnp
-
-        karr = key_cols[key_names[0]]
-        if jnp.issubdtype(karr.dtype, jnp.integer):
-            if range_hint is not None:
-                kmin, kmax = range_hint
-            else:
-                kmin_a, kmax_a = _get_compiled_minmax(mesh)(karr, valid0)
-                kmin_a.copy_to_host_async()
-                kmax_a.copy_to_host_async()
-                kmin = int(np_.asarray(jax.device_get(kmin_a))[0])
-                kmax = int(np_.asarray(jax.device_get(kmax_a))[0])
-            rng = kmax - kmin + 1
-            if 0 < rng <= _DENSE_MAX_RANGE:
-                buckets = dense_buckets(rng)
-                return _dense_groupby_partials(
-                    mesh, key_names[0], karr, agg_cols, valid0, kmin, buckets
-                )
+    ranges = dict(key_ranges or {})
+    unknown = [n for n in key_names if n not in ranges]
+    if all(np_.issubdtype(np_.dtype(key_cols[n].dtype), np_.integer) for n in unknown):
+        for n in unknown:
+            kmin_a, kmax_a = _get_compiled_minmax(mesh)(key_cols[n], valid0)
+            kmin_a.copy_to_host_async()
+            kmax_a.copy_to_host_async()
+            ranges[n] = (
+                int(np_.asarray(jax.device_get(kmin_a))[0]),
+                int(np_.asarray(jax.device_get(kmax_a))[0]),
+            )
+    if all(n in ranges for n in key_names):
+        known = [ranges[n] for n in key_names]
+        radices = [hi - lo + 1 for lo, hi in known]
+        if all(r > 0 for r in radices) and math.prod(radices) <= _DENSE_MAX_RANGE:
+            return _dense_groupby_partials(mesh, key_cols, known, agg_cols, valid0)
     agg_sig, arrays = _dedupe_cols(agg_cols)
     compiled = _get_compiled_kernel(mesh, len(key_names), agg_sig)
     valid = valid0

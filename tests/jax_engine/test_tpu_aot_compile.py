@@ -17,6 +17,7 @@ answers one JSON line each.
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
@@ -87,6 +88,45 @@ def sort_segment_aggregate(topo: Any) -> None:
     f.lower(
         _arg(mesh, ROWS, jnp.int64), _arg(mesh, ROWS, jnp.float64), _arg(mesh, ROWS, jnp.bool_)
     ).compile()
+
+
+@_case
+def masked_group_aggregate(topo: Any, chips: str, radices: str) -> None:
+    """TPC-H Q1's aggregate: 6M rows, two dictionary-code keys packed into
+    one group index, float64 sums (NaN-aware and not), counts, min and max
+    by masked reductions; and the widest group index they take."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from fugue_tpu.ops import segment
+
+    mesh = _mesh(topo, int(chips))
+    ranges = tuple((-1, int(r) - 2) for r in radices.split("x"))
+    buckets = segment.dense_buckets(math.prod(int(r) for r in radices.split("x")))
+    assert buckets <= segment.MAX_MASKED_BUCKETS
+    sig = (
+        ("s", "sum", 0, True),
+        ("n", "count", 0, True),
+        ("lo", "min", 1, False),
+        ("hi", "max", 1, False),
+        ("c", "sum", 2, False),
+    )
+    n = 6_000_000
+    segment._get_compiled_pack(len(ranges)).lower(
+        _arg(mesh, (len(ranges),), jnp.int64, P()),
+        _arg(mesh, (len(ranges),), jnp.int32, P()),
+        *[_arg(mesh, n, jnp.int32) for _ in ranges],
+    ).compile()
+    lowered = segment._get_compiled_dense(mesh, buckets, sig).lower(
+        _arg(mesh, n, jnp.int32),
+        _arg(mesh, (), jnp.int64, P()),
+        _arg(mesh, n, jnp.float64),
+        _arg(mesh, n, jnp.float64),
+        _arg(mesh, n, jnp.int64),
+        _arg(mesh, n, jnp.bool_),
+    )
+    assert "stablehlo.while" in lowered.as_text()  # the masked loop, not the scatter
+    lowered.compile()
 
 
 @_case
@@ -281,6 +321,12 @@ def test_dense_segment_aggregate(aot):
 
 def test_sort_segment_aggregate(aot):
     aot("sort_segment_aggregate")
+
+
+@pytest.mark.parametrize("radices", ["4x3", "91x90"])
+@pytest.mark.parametrize("chips", [1, 4])
+def test_masked_group_aggregate(aot, chips, radices):
+    aot("masked_group_aggregate", chips, radices)
 
 
 def test_join_probe(aot):

@@ -3,7 +3,9 @@
 A Q1-shaped query (filter, a two-key aggregate of expressions, ORDER BY),
 a Q3-shaped one (two joins, a group-by on a renamed key, ORDER BY and
 LIMIT) and a LOAD then SELECT run through FugueSQL on the JAX engine with
-tracing on; each query's spans are read back as a tree.
+tracing on; each query's spans are read back as a tree. Each aggregate
+runs on the device after one ``agg.project``; only the ORDER BY (and
+Q3's LIMIT) run on the host.
 """
 
 from collections import Counter
@@ -53,6 +55,7 @@ QUERY_PATH = {
     "workflow.task",
     "engine.select",
     "engine.join",
+    "agg.project",
     "device.wait",
     "host.op",
     "frame.fetch",
@@ -135,11 +138,15 @@ def _parent(rec, recs):
 
 
 KINDS = ["q1", "q3", "load"]
-REASON = {
-    "q1": "agg_plan_refused:agg_of_expression",
-    "q3": "agg_plan_refused:computed_keys",
-    "load": "agg_plan_refused:agg_of_expression",
-}
+
+
+def _ancestors(rec, recs):
+    out = []
+    p = _parent(rec, recs)
+    while p is not None:
+        out.append(p)
+        p = _parent(p, recs)
+    return out
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -148,20 +155,17 @@ def test_span_tree(engine, tracer, tmp_path, kind):
     recs = tracer.records()
     names = _by_name(recs)
     assert all(p["args"]["chars"] > 0 for p in names["sql.parse"])
-    # the select's aggregate falls back to the host, and says why
-    selects = [
-        h for h in names["host.op"] if h["args"]["op"] == "select"
-    ]
-    assert len(selects) == 1
-    sel = selects[0]
-    assert sel["args"]["reason"] == REASON[kind]
-    assert sel["args"]["rows_in"] > 0
-    assert _parent(sel, recs)["name"] == "engine.select"
-    # its input is read back from the device inside it
-    fetch = [f for f in names["frame.fetch"] if f["parent"] == sel["id"]]
-    assert len(fetch) == 1
-    assert fetch[0]["args"]["rows"] == sel["args"]["rows_in"]
-    assert fetch[0]["args"]["bytes"] > 0
+    # the select's aggregate runs on the device: no host select
+    assert not any(h["args"]["op"] in ("select", "aggregate") for h in names["host.op"])
+    assert not any(h["args"]["reason"].startswith("agg_plan_refused") for h in names["host.op"])
+    # one projection makes the expression argument's helper column
+    (proj,) = names["agg.project"]
+    assert proj["args"]["args"] == ["__agg_arg0"] and proj["args"]["keys"] == []
+    sel = _parent(proj, recs)
+    assert sel["name"] == "engine.select"
+    # the select's input is not read back from the device
+    assert not any(f["args"]["rows"] == N_ITEMS for f in names.get("frame.fetch", []))
+    assert not any(sel in _ancestors(f, recs) for f in names.get("frame.fetch", []))
     # the ORDER BY sorts on the host
     assert any(
         h["args"]["op"] == "sort" and h["args"]["reason"] == "order_by"
@@ -215,7 +219,7 @@ def test_query_path_spans_enter_the_annotation(engine, tracer, tmp_path, kind):
     finally:
         tracer._ann_cls = saved
     recorded = Counter(r["name"] for r in tracer.records() if r["name"] in QUERY_PATH)
-    assert recorded["host.op"] >= 2 and recorded["frame.fetch"] >= 1
+    assert recorded["agg.project"] == 1 and recorded["host.op"] >= 1
     annotated = Counter(n for n in _Recording.entered if n in QUERY_PATH)
     assert annotated == recorded
 
@@ -237,13 +241,16 @@ def test_host_ops_counters_match_the_spans(engine, tracer, tmp_path, kind):
     assert {(o, why): n for o, d in got["rows_in"].items() for why, n in d.items()} == rows
     for span, prefix, count in (("frame.fetch", "fetch", "fetches"), ("frame.ingest", "ingest", "ingests")):
         mine = [r for r in recs if r["name"] == span]
-        assert got[count] == len(mine) > 0
+        assert got[count] == len(mine)
         assert got[f"{prefix}_rows"] == sum(r["args"]["rows"] for r in mine)
         assert got[f"{prefix}_bytes"] == sum(r["args"]["bytes"] for r in mine)
+    # the items frame is ingested; the aggregate's few rows reach the
+    # ORDER BY before any ingest, so nothing is fetched back
+    assert got["ingests"] > 0 and got["fetches"] == 0
     # the same counters on the /metrics page
     text = to_prometheus_text(engine=engine)
     validate_prometheus_text(text)
-    assert f"fugue_tpu_host_ops_calls_select_{REASON[kind]} 1" in text.splitlines()
+    assert "fugue_tpu_host_ops_calls_sort_order_by 1" in text.splitlines()
 
 
 @pytest.mark.parametrize("kind", KINDS)
