@@ -66,10 +66,14 @@ def non_deterministic(func: Any) -> Any:
 
 
 class _Refused(Exception):
-    """Internal control flow: this node can't be fingerprinted."""
+    """Internal control flow: this node can't be fingerprinted. ``code`` is
+    a fixed short name of the refusal's kind, written at the raise site and
+    never built from the plan's data or names (a counter label); ``reason``
+    is the prose for a human."""
 
-    def __init__(self, reason: str):
+    def __init__(self, code: str, reason: str):
         super().__init__(reason)
+        self.code = code
         self.reason = reason
 
 
@@ -77,13 +81,15 @@ class FingerprintReport:
     """Per-task fingerprints of one (post-optimization) task list.
 
     ``fps[id(task)]`` is the fingerprint string or ``None`` (refused /
-    poisoned); ``reasons`` explains every ``None``; ``source_bytes``
+    poisoned); ``reasons`` explains every ``None`` and ``codes`` names its
+    kind (``_Refused.code``); ``source_bytes``
     records the producer-side bytes behind Load/CreateData tasks so the
     planner can report how much a cache cut skipped."""
 
     def __init__(self) -> None:
         self.fps: Dict[int, Optional[str]] = {}
         self.reasons: Dict[int, str] = {}
+        self.codes: Dict[int, str] = {}
         self.source_bytes: Dict[int, int] = {}
         # delta keys: the same recursive hash with every Load's per-file
         # list replaced by its PATH — the identity of "this chain over
@@ -139,11 +145,13 @@ def fingerprint_tasks(
         if isinstance(task, OutputTask):
             rep.fps[id(task)] = None
             rep.reasons[id(task)] = "output sink (side effects run every time)"
+            rep.codes[id(task)] = "output_sink"
             continue
         in_fps = [rep.fps.get(id(d)) for d in task.inputs]
         if any(f is None for f in in_fps):
             rep.fps[id(task)] = None
             rep.reasons[id(task)] = "poisoned by unfingerprintable input"
+            rep.codes[id(task)] = "poisoned_input"
             continue
         in_delta = [rep.delta_fps.get(id(d)) for d in task.inputs]
         try:
@@ -163,9 +171,11 @@ def fingerprint_tasks(
         except _Refused as r:
             rep.fps[id(task)] = None
             rep.reasons[id(task)] = r.reason
+            rep.codes[id(task)] = r.code
         except Exception as ex:  # fingerprinting must never fail a run
             rep.fps[id(task)] = None
             rep.reasons[id(task)] = f"fingerprint error: {type(ex).__name__}"
+            rep.codes[id(task)] = "fingerprint_error"
     return rep
 
 
@@ -205,7 +215,9 @@ def _task_fp(
     if getattr(ext, _NON_DETERMINISTIC_ATTR, False) or getattr(
         type(ext), _NON_DETERMINISTIC_ATTR, False
     ):
-        raise _Refused("extension marked non-deterministic")
+        raise _Refused(
+            "nondeterministic_extension", "extension marked non-deterministic"
+        )
     common: List[Any] = [
         FP_VERSION,
         engine_kind,
@@ -246,21 +258,24 @@ def _task_fp(
             # RNGs) — Load and CreateData are the content-addressable
             # creation paths; everything else refuses by design
             raise _Refused(
+                "opaque_creator",
                 f"opaque creator {type(ext).__name__} (external input is "
                 "not content-addressable)"
             )
     elif kind == K_SAMPLE:
         if task.params.get_or_none("seed", int) is None:
-            raise _Refused("sample without an explicit seed")
+            raise _Refused("unseeded_sample", "sample without an explicit seed")
         both(_params_fp(task, max_bytes))
     else:
         from ..extensions._builtins import processors as bp
 
         if isinstance(ext, bp.SaveAndUse):
-            raise _Refused("save_and_use writes storage (raw side effect)")
+            raise _Refused(
+                "save_and_use", "save_and_use writes storage (raw side effect)"
+            )
         if isinstance(ext, bp.RunTransformer):
             if task.params.get_or_none("callback", object) is not None:
-                raise _Refused("transformer uses an RPC callback")
+                raise _Refused("rpc_callback", "transformer uses an RPC callback")
             both(_udf_fp(task.params.get_or_throw("transformer", object)))
             both(_params_fp(task, max_bytes, skip=("transformer", "callback")))
         else:
@@ -301,7 +316,7 @@ def _extension_fp(ext: Any) -> str:
 def _load_fp(task: FugueTask, rep: FingerprintReport) -> List[Any]:
     path = task.params.get_or_none("path", object)
     if not isinstance(path, str) or path == "":
-        raise _Refused("load path is not a plain string")
+        raise _Refused("load_path_not_string", "load path is not a plain string")
     files: List[str] = []
     if _glob.has_magic(path):
         files = sorted(_glob.glob(path))
@@ -312,7 +327,9 @@ def _load_fp(task: FugueTask, rep: FingerprintReport) -> List[Any]:
     elif os.path.exists(path):
         files = [path]
     if len(files) == 0:
-        raise _Refused(f"load source {path} does not exist (yet)")
+        raise _Refused(
+            "load_source_missing", f"load source {path} does not exist (yet)"
+        )
     out: List[Any] = []
     total = 0
     for f in files:
@@ -339,14 +356,16 @@ def _data_fp(data: Any, max_bytes: int) -> Any:
     if data is None:
         return ("none", 0)
     if isinstance(data, Yielded):
-        raise _Refused("yielded handle (depends on another run)")
+        raise _Refused("yielded_handle", "yielded handle (depends on another run)")
     if isinstance(data, pa.Table):
         return _arrow_fp(data, max_bytes)
     if isinstance(data, pd.DataFrame):
         return _pandas_fp(data, max_bytes)
     if isinstance(data, DataFrame):
         if data.is_local and not data.is_bounded:
-            raise _Refused("one-pass stream input (hashing would consume it)")
+            raise _Refused(
+                "stream_input", "one-pass stream input (hashing would consume it)"
+            )
         if isinstance(data, ArrowDataFrame):
             return _arrow_fp(data.native, max_bytes)
         if isinstance(data, PandasDataFrame):
@@ -357,18 +376,20 @@ def _data_fp(data: Any, max_bytes: int) -> Any:
                 len(data.native) * max(1, len(data.schema)) * 16,
             )
         raise _Refused(
+            "frame_without_digest",
             f"{type(data).__name__} input (no content digest; identity-of-"
             "object is refused)"
         )
     if isinstance(data, (list, tuple)):
         return (to_uuid(data), len(data) * 16)
-    raise _Refused(f"{type(data).__name__} create input")
+    raise _Refused("unknown_create_input", f"{type(data).__name__} create input")
 
 
 def _arrow_fp(tbl: "Any", max_bytes: int) -> Any:
     nbytes = int(tbl.nbytes)
     if nbytes > max_bytes:
         raise _Refused(
+            "over_fingerprint_max_bytes",
             f"table of {nbytes} bytes exceeds fingerprint_max_bytes={max_bytes}"
         )
     h = md5()
@@ -392,6 +413,7 @@ def _pandas_fp(pdf: "Any", max_bytes: int) -> Any:
     nbytes = int(pdf.memory_usage(index=False, deep=False).sum())
     if nbytes > max_bytes:
         raise _Refused(
+            "over_fingerprint_max_bytes",
             f"frame of {nbytes} bytes exceeds fingerprint_max_bytes={max_bytes}"
         )
     h = md5()
@@ -401,7 +423,7 @@ def _pandas_fp(pdf: "Any", max_bytes: int) -> Any:
         hashed = pd.util.hash_pandas_object(pdf, index=False)
         h.update(hashed.values.tobytes())
     except Exception:
-        raise _Refused("pandas content not hashable")
+        raise _Refused("pandas_unhashable", "pandas content not hashable")
     return ("pandas", h.hexdigest()), nbytes
 
 
@@ -430,7 +452,7 @@ def _source_hash_of(obj: Any) -> str:
     except Exception:
         code = getattr(obj, "__code__", None)
         if code is None:
-            raise _Refused(f"no source or bytecode for {obj!r}")
+            raise _Refused("no_source", f"no source or bytecode for {obj!r}")
         out = md5(
             code.co_code + repr(code.co_consts).encode() + repr(code.co_names).encode()
         ).hexdigest()
@@ -443,7 +465,10 @@ def _callable_fp(func: Any) -> str:
     """Source + defaults + closure-cell contents: two factory-made UDFs
     sharing source but closing over different values must differ."""
     if getattr(func, _NON_DETERMINISTIC_ATTR, False):
-        raise _Refused(f"{getattr(func, '__name__', func)!r} marked non-deterministic")
+        raise _Refused(
+            "nondeterministic_function",
+            f"{getattr(func, '__name__', func)!r} marked non-deterministic",
+        )
     parts: List[Any] = [_source_hash_of(func)]
     defaults = getattr(func, "__defaults__", None)
     if defaults:
@@ -459,7 +484,9 @@ def _udf_fp(tf: Any) -> str:
     the actual code behind it."""
     func = getattr(getattr(tf, "_wrapper", None), "_func", None)
     if func is not None and getattr(func, _NON_DETERMINISTIC_ATTR, False):
-        raise _Refused("transformer function marked non-deterministic")
+        raise _Refused(
+            "nondeterministic_function", "transformer function marked non-deterministic"
+        )
     parts: List[Any] = []
     try:
         parts.append(tf.__uuid__())
@@ -480,7 +507,7 @@ def _value_token(v: Any, depth: int) -> Any:
     import pyarrow as pa
 
     if depth > 6:
-        raise _Refused("param nesting too deep")
+        raise _Refused("param_too_deep", "param nesting too deep")
     if v is None or isinstance(v, (bool, int, float, str, bytes)):
         return v
     if isinstance(v, (pd.DataFrame, pa.Table)):
@@ -500,7 +527,9 @@ def _value_token(v: Any, depth: int) -> Any:
         return _callable_fp(v)
     r = repr(v)
     if " at 0x" in r:
-        raise _Refused(f"param {type(v).__name__} has no stable identity")
+        raise _Refused(
+            "param_without_identity", f"param {type(v).__name__} has no stable identity"
+        )
     return r
 
 

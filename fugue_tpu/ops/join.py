@@ -37,15 +37,20 @@ _JOIN_CACHE: Dict[Any, Any] = {}
 MAX_BROADCAST_ROWS = 1 << 20
 # per-shard output-slot budget for the 1:N expansion join
 MAX_EXPAND_ROWS = 1 << 22
+# the right side's invalid rows sort under this hash, which no valid row's
+# hash reaches (the top two hash values are one: a collision at worst)
+_TOP_HASH = 0xFFFFFFFFFFFFFFFF
 
 
 def _key_hash_and_valid(jnp: Any, key_cols: List[Any], valid: Any):
-    """(u64 hash, validity excluding NaN keys) for a set of key columns."""
+    """(u64 hash below ``_TOP_HASH``, validity excluding NaN keys) for a
+    set of key columns."""
     kv = valid
     for c in key_cols:
         if jnp.issubdtype(c.dtype, jnp.floating):
             kv = kv & ~jnp.isnan(c)
-    return _hash_cols(jnp, key_cols), kv
+    h = jnp.minimum(_hash_cols(jnp, key_cols), jnp.uint64(_TOP_HASH - 1))
+    return h, kv
 
 
 def _probe_body(
@@ -112,20 +117,15 @@ def _get_compiled_right_prep(mesh: Any, n_keys: int, dtypes: Any, local: bool):
 
         def join_right_prep(valid: Any, *key_cols: Any):
             h, kv = _key_hash_and_valid(jnp, list(key_cols), valid)
-            n = h.shape[0]
-            inv = jnp.logical_not(kv)
-            iota = lax.iota(jnp.int32, n)
-            s_inv, s_h, order = lax.sort((inv, h, iota), num_keys=2)
+            top = jnp.uint64(_TOP_HASH)
+            # invalid rows take the top hash, so they sort to the tail and
+            # the array stays globally sorted for searchsorted (the idx < nv
+            # guard keeps them unmatchable). One 64-bit sort key: a sort
+            # over (invalid, hash) compiled about 2.6 times longer for a v5e
+            iota = lax.iota(jnp.int32, h.shape[0])
+            s_h, order = lax.sort((jnp.where(kv, h, top), iota), num_keys=1)
             nv = kv.sum(dtype=jnp.int64)
-            dup = jnp.any(
-                (s_h[1:] == s_h[:-1])
-                & jnp.logical_not(s_inv[1:])
-                & jnp.logical_not(s_inv[:-1])
-            )
-            # invalid rows sit at the tail but keep arbitrary hashes — pin
-            # them to the max so the array stays globally sorted for
-            # searchsorted (the idx < nv guard keeps them unmatchable)
-            s_h = jnp.where(s_inv, jnp.uint64(0xFFFFFFFFFFFFFFFF), s_h)
+            dup = jnp.any((s_h[1:] == s_h[:-1]) & (s_h[1:] != top))
             return s_h, order, nv[None], dup[None]
 
         if local:

@@ -24,17 +24,26 @@ semantics a served result share implies; see docs/serving.md).
 """
 
 import hashlib
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 from .._utils.params import ParamDict
 from ..workflow._tasks import OutputTask
 
-__all__ = ["submission_key"]
+__all__ = ["keyed_submission", "submission_key"]
 
 
 def submission_key(dag: Any, engine: Any, conf: Any = None) -> Optional[str]:
     """The in-flight dedup key for submitting ``dag`` to ``engine``, or
-    ``None`` when the plan can't be fully fingerprinted (no dedup).
+    ``None`` when the plan can't be fully fingerprinted (no dedup)."""
+    return keyed_submission(dag, engine, conf)[0]
+
+
+def keyed_submission(
+    dag: Any, engine: Any, conf: Any = None
+) -> Tuple[Optional[str], Optional[str]]:
+    """``(key, None)``, or ``(None, refused)`` with the refusal code
+    (``cache/fingerprint.py`` ``_Refused.code``) of the first task that
+    refused to fingerprint, ``plan_error`` when the dry optimize raised.
 
     Runs the same optimize→fingerprint pipeline the run path will run
     (dry: ``optimize_tasks`` clones, it never mutates the compiled
@@ -57,7 +66,7 @@ def submission_key(dag: Any, engine: Any, conf: Any = None) -> Optional[str]:
         )
         fpr = fingerprint_tasks(run_tasks, plan_conf, type(engine).__name__)
     except Exception:
-        return None  # an unplannable dag fails at run time, not here
+        return None, "plan_error"  # an unplannable dag fails at run time
     parts = []
     for t in run_tasks:
         fp = fpr.fp(t)
@@ -65,12 +74,13 @@ def submission_key(dag: Any, engine: Any, conf: Any = None) -> Optional[str]:
             parts.append(fp)
             continue
         if not isinstance(t, OutputTask):
-            return None  # refusal anywhere = no dedup, never a wrong share
+            # refusal anywhere = no dedup, never a wrong share
+            return None, fpr.codes.get(id(t), "unknown")
         in_fps = [fpr.fp(d) for d in t.inputs]
         if any(f is None for f in in_fps):
-            return None
+            return None, "poisoned_input"
         parts.append("out:" + t.__uuid__() + ":" + ",".join(in_fps))
     # both waiters read results by yield name — the mapping is part of
     # the identity (same plan, different names = different submissions)
     parts.append("yields:" + ",".join(sorted(dag.yields.keys())))
-    return hashlib.md5("|".join(parts).encode()).hexdigest()
+    return hashlib.md5("|".join(parts).encode()).hexdigest(), None

@@ -61,7 +61,7 @@ from ..constants import (
 )
 from ..resilience import SITE_SERVE_CLAIM, SITE_SERVE_JOURNAL, FaultInjector
 from ..workflow.factory import build_workflow, is_workflow_factory
-from .dedup import submission_key
+from .dedup import keyed_submission
 from .fleet import FleetCoordinator, FleetResult
 from .journal import SubmissionJournal
 from .stats import ServeStats
@@ -541,7 +541,11 @@ class EngineServer:
             )
             if pol.conf_overlay:
                 dag._conf.update(pol.conf_overlay)
-            key = submission_key(dag, self._engine)
+            with tracer.span("serve.key", cat="serve", annotate=True) as ksp:
+                key, refused = keyed_submission(dag, self._engine)
+                ksp.set(refused=refused)
+            if refused is not None:
+                self._stats.inc_refused(refused)
             reserve = (
                 int(reserve_bytes) if reserve_bytes is not None else self.default_reserve
             )
@@ -799,19 +803,24 @@ class EngineServer:
                 ex.started = True
                 ex.started_at = time.monotonic()
                 ex.state = "running"
+                concurrent = self._active
                 self._active += 1
             try:
-                self._run_execution(ex)
+                self._run_execution(ex, concurrent)
             finally:
                 with self._cv:
                     self._active -= 1
 
-    def _run_execution(self, ex: _Execution) -> None:
+    def _run_execution(self, ex: _Execution, concurrent: int) -> None:
+        """Run one execution on this worker; ``concurrent`` is the number
+        of executions already running when it was picked."""
         from ..obs import get_tracer
 
         tracer = get_tracer()
         wait_s = (ex.started_at or ex.submitted_at) - ex.submitted_at
         self._stats.inc("executions")
+        if concurrent:
+            self._stats.inc("concurrent_starts")
         # tenant attribution: the run's span-histogram samples (and every
         # thread the run forks — contexts propagate) carry tenant=<id>;
         # workflow.run's own run_labels nests inside and overlays its
@@ -864,6 +873,7 @@ class EngineServer:
                         priority=ex.priority,
                         waiters=len(ex.waiters),
                         queue_wait_s=round(wait_s, 6),
+                        concurrent=concurrent,
                     ):
                         result = ex.dag.run(self._engine)
                     ex.result = result
@@ -886,6 +896,16 @@ class EngineServer:
                     # so a cross-replica waiter re-decides (executes) rather
                     # than caching a failure fleet-wide
                     self._fleet.release(ex.key)
+            with tracer.span(
+                "serve.deliver", cat="serve", annotate=True, tenant=ex.tenant
+            ) as sp:
+                self._deliver(ex, wait_s, sp)
+        self._maybe_publish_spool()
+
+    def _deliver(self, ex: _Execution, wait_s: float, sp: Any) -> None:
+        """From a finished run to its waiters woken: the counters, the
+        result's rows and bytes charged to each waiter's tenant, the
+        journal, the wake-up and the retention ring."""
         # a finished execution keeps its result, not its workflow: the
         # workflow's context holds every intermediate frame, and retained
         # submissions would keep those on the device
@@ -896,6 +916,7 @@ class EngineServer:
             self._stats.inc("failed")
         measured = _result_bytes(ex.result) if ex.state == "done" else 0
         rows = _result_rows(ex.result) if ex.state == "done" else 0
+        sp.set(rows=rows, bytes=measured)
         run_s = (ex.finished_at or 0.0) - (ex.started_at or 0.0)
         with self._lock:
             if ex.key is not None and self._inflight.get(ex.key) is ex:
@@ -916,7 +937,6 @@ class EngineServer:
                 self._stats.inc("journal_appends")
         self._finish_waiters(ex)
         self._retire(waiters)
-        self._maybe_publish_spool()
 
     def _maybe_publish_spool(self) -> None:
         """Cumulative, idempotent span export (obs/spool.py): last write

@@ -27,6 +27,7 @@ _COUNTERS = (
     "rejected_queue_full",
     "rejected_budget",
     "executions",           # executions actually started on a worker
+    "concurrent_starts",    # executions started while another was running
     "completed",
     "failed",
     "canceled",             # submissions canceled by their owner
@@ -77,6 +78,13 @@ class ServeStats:
                 self._t.popitem(last=False)
                 self._c["tenant_evictions"] = self._c.get("tenant_evictions", 0) + 1
 
+    def inc_refused(self, code: str) -> None:
+        """One submission whose plan refused a dedup key, by the refusal's
+        code (``cache/fingerprint.py``): fixed names written in the
+        program, so a bounded set of labels."""
+        with self._lock:
+            self._refused[code] = self._refused.get(code, 0) + 1
+
     def get(self, name: str) -> float:
         with self._lock:
             return self._c.get(name, 0)
@@ -84,6 +92,7 @@ class ServeStats:
     def as_dict(self) -> Dict[str, object]:
         with self._lock:
             out: Dict[str, object] = {k: self._c.get(k, 0) for k in _COUNTERS}
+            out["key_refused"] = dict(sorted(self._refused.items()))
             out["tenants"] = {
                 tid: {
                     k: (round(v, 6) if isinstance(v, float) else v)
@@ -96,4 +105,5 @@ class ServeStats:
     def reset(self) -> None:
         with self._lock:
             self._c: Dict[str, float] = {}
+            self._refused: Dict[str, int] = {}
             self._t: "OrderedDict[str, Dict[str, float]]" = OrderedDict()

@@ -91,6 +91,44 @@ def sort_segment_aggregate(topo: Any) -> None:
 
 
 @_case
+def q3_sort_aggregate(topo: Any) -> None:
+    """TPC-H Q3's aggregate at SF1: about 65,536 joined rows grouped by
+    three keys (int64, date32, int64), each sum a float64. One
+    ``lax.sort`` over these keys took about four minutes to compile for a
+    v5e; the chain of one-key sorts takes seconds."""
+    import jax.numpy as jnp
+
+    from fugue_tpu.ops import segment
+
+    mesh = _mesh(topo, 1)
+    n = 1 << 16
+    f = segment._get_compiled_kernel(mesh, 3, (("r", "sum", 0, False),))
+    f.lower(
+        _arg(mesh, n, jnp.int64),
+        _arg(mesh, n, jnp.int32),
+        _arg(mesh, n, jnp.int64),
+        _arg(mesh, n, jnp.float64),
+        _arg(mesh, n, jnp.bool_),
+    ).compile()
+
+
+@_case
+def join_right_prep(topo: Any) -> None:
+    """The shuffle join's right-side prep over Q3's filtered lineitem at
+    SF1 (4M slots): one sort over one 64-bit key, invalid rows under the
+    top hash."""
+    import jax.numpy as jnp
+
+    from fugue_tpu.ops import join
+
+    mesh = _mesh(topo, 1)
+    n = 1 << 22
+    join._get_compiled_right_prep(mesh, 1, ("int64",), True).lower(
+        _arg(mesh, n, jnp.bool_), _arg(mesh, n, jnp.int64)
+    ).compile()
+
+
+@_case
 def masked_group_aggregate(topo: Any, chips: str, radices: str) -> None:
     """TPC-H Q1's aggregate: 6M rows, two dictionary-code keys packed into
     one group index, float64 sums (NaN-aware and not), counts, min and max
@@ -321,6 +359,14 @@ def test_dense_segment_aggregate(aot):
 
 def test_sort_segment_aggregate(aot):
     aot("sort_segment_aggregate")
+
+
+def test_q3_sort_aggregate(aot):
+    aot("q3_sort_aggregate")
+
+
+def test_join_right_prep(aot):
+    aot("join_right_prep")
 
 
 @pytest.mark.parametrize("radices", ["4x3", "91x90"])

@@ -17,6 +17,7 @@ import pandas as pd
 import pytest
 
 from fugue_tpu import FugueWorkflow
+from fugue_tpu.cache import non_deterministic
 from fugue_tpu.column import col, functions as ff
 from fugue_tpu.constants import (
     FUGUE_TPU_CONF_SERVE_DEFAULT_PRIORITY,
@@ -24,7 +25,7 @@ from fugue_tpu.constants import (
     FUGUE_TPU_CONF_SERVE_QUEUE_DEPTH,
 )
 from fugue_tpu.execution import NativeExecutionEngine
-from fugue_tpu.obs import get_sampler, get_span_metrics, get_tracer
+from fugue_tpu.obs import get_sampler, get_span_metrics, get_tracer, to_prometheus_text
 from fugue_tpu.serve import (
     EngineServer,
     ServeHttpClient,
@@ -33,6 +34,7 @@ from fugue_tpu.serve import (
     submission_key,
     tenant_policy,
 )
+from fugue_tpu.serve.dedup import keyed_submission
 
 
 def _agg_dag(seed: int = 0, rows: int = 64) -> FugueWorkflow:
@@ -247,6 +249,56 @@ def test_dedup_key_identity_and_refusal():
     dag = FugueWorkflow()
     dag.create(gen, schema="a:int").yield_dataframe_as("g", as_local=True)
     assert submission_key(dag, eng) is None
+
+
+def test_dedup_key_refusal_names_its_code():
+    eng = NativeExecutionEngine()
+    key, refused = keyed_submission(_agg_dag(seed=7), eng)
+    assert key == submission_key(_agg_dag(seed=7), eng) and refused is None
+
+    def gen() -> pd.DataFrame:
+        return pd.DataFrame({"a": [1]})
+
+    dag = FugueWorkflow()
+    dag.create(gen, schema="a:int").yield_dataframe_as("g", as_local=True)
+    key, refused = keyed_submission(dag, eng)
+    assert key is None and refused == "opaque_creator"
+    with EngineServer(eng) as srv:
+        srv.submit(dag).result(timeout=60)
+        srv.submit(_agg_dag()).result(timeout=60)
+    assert eng.stats()["serve"]["key_refused"] == {"opaque_creator": 1}
+
+
+def test_dedup_key_refusal_never_names_the_tenants_code():
+    # refusals whose prose names a user's function or class: the counter
+    # label is the fixed code, so no tenant-chosen name reaches /metrics
+    eng = NativeExecutionEngine()
+
+    @non_deterministic
+    def tenant_secret_fn(df: pd.DataFrame) -> pd.DataFrame:
+        return df
+
+    class TenantSecretParam:
+        pass
+
+    def passthrough(df: pd.DataFrame, p: object) -> pd.DataFrame:
+        return df
+
+    def dag_of(fn, **params) -> FugueWorkflow:
+        dag = FugueWorkflow()
+        df = dag.df(pd.DataFrame({"a": [1, 2]}))
+        df.transform(fn, schema="*", params=params).yield_dataframe_as("t", as_local=True)
+        return dag
+
+    dags = [dag_of(tenant_secret_fn), dag_of(passthrough, p=TenantSecretParam())]
+    with EngineServer(eng) as srv:
+        for dag in dags:
+            srv.submit(dag).result(timeout=60)
+    refused = eng.stats()["serve"]["key_refused"]
+    assert refused == {"nondeterministic_function": 1, "param_without_identity": 1}
+    page = to_prometheus_text(eng)
+    assert "fugue_tpu_serve_key_refused_param_without_identity" in page
+    assert "secret" not in page.lower()
 
 
 def test_serve_stats_mounted_on_engine_registry_and_probes():
